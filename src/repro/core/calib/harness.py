@@ -216,12 +216,21 @@ class KernelBackend:
             return False
         return True
 
+    @staticmethod
+    def kernel_mode() -> str:
+        """Pallas mode to time: compiled on a TPU backend, interpreted elsewhere."""
+        import jax
+
+        return "tpu" if jax.default_backend() == "tpu" else "interpret"
+
     def _measure_full(self, arch: str, shape: str) -> Dict:
         key = (arch, shape)
         if key not in self._full_cache:
             from benchmarks.kernel_bench import measure_calibration_kernel
 
-            meas = measure_calibration_kernel(arch, n=self.n_samples)
+            meas = measure_calibration_kernel(
+                arch, mode=self.kernel_mode(), n=self.n_samples
+            )
             rec = dict(self.seed_db[(arch, shape, self.sku.full_profile)])
             # the kernel's wall time *is* the measured compute term; the
             # seed's memory/collective proportions ride along so the record

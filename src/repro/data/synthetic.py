@@ -84,10 +84,15 @@ def token_batch(
 def batch_for(model_cfg, suite, *, seed: int = 0, epoch: int = 0, step: int = 0):
     """Shape-correct batch for any (config, suite) — mirrors input_specs."""
     if model_cfg.family == "resnet":
-        spec = FOR_WORKLOAD.get(
-            model_cfg.name,
-            DatasetSpec("custom", 45_000, 5_000, model_cfg.img_size, model_cfg.n_classes),
-        )
+        spec = FOR_WORKLOAD.get(model_cfg.name)
+        # a reduced config keeps the workload's name but not its image size
+        # or class count; the batch follows the config (its input_specs)
+        if spec is None or (spec.image_size, spec.n_classes) != (
+            model_cfg.img_size, model_cfg.n_classes
+        ):
+            spec = DatasetSpec(
+                "custom", 45_000, 5_000, model_cfg.img_size, model_cfg.n_classes
+            )
         return image_batch(spec, suite.global_batch, seed=seed, epoch=epoch, step=step)
     extras = {}
     B = suite.global_batch

@@ -5,9 +5,12 @@ seq_len-deep cache: the op is *memory-bound* (arithmetic intensity
 ≈ 2 FLOPs/byte « the 240 FLOP/byte ridge), so the kernel is shaped around
 HBM→VMEM streaming, not MXU occupancy:
 
-  * grid (B, KVH, nk) with the KV dim innermost: each (batch, kv-head)
-    streams its KV stripe block-by-block through VMEM exactly once while the
-    (G, D) query tile and the f32 accumulator stay resident;
+  * grid (B, nk) with the KV dim innermost: each sequence streams its KV
+    stripe block-by-block through VMEM exactly once while the (KVH, G, D)
+    query tile and the f32 accumulator stay resident. A block holds every KV
+    head, so the cache is read in its stored (B, S, KVH, D) layout (a
+    one-head block would be a (1, D) slab, which the TPU's (8, 128) tiling
+    refuses);
   * ``block_k`` is sized so two KV blocks (k + v, bf16) fit VMEM alongside
     the accumulator, letting the implicit Pallas double-buffering overlap
     the next block's DMA with the current block's compute;
@@ -32,19 +35,19 @@ LANES = 128
 
 def _decode_kernel(
     kv_len_ref,  # SMEM (1,) int32 — scalar prefetch
-    q_ref,  # (1, 1, G, D)
-    k_ref,  # (1, block_k, 1, D)
-    v_ref,  # (1, block_k, 1, D)
-    o_ref,  # (1, 1, G, D)
-    acc,  # VMEM (G, D) f32
-    m,  # VMEM (G, LANES) f32
-    l,  # VMEM (G, LANES) f32
+    q_ref,  # (1, KVH, G, D)
+    k_ref,  # (1, block_k, KVH, D)
+    v_ref,  # (1, block_k, KVH, D)
+    o_ref,  # (1, KVH, G, D)
+    acc,  # VMEM (KVH, G, D) f32
+    m,  # VMEM (KVH, G, LANES) f32
+    l,  # VMEM (KVH, G, LANES) f32
     *,
     scale: float,
     block_k: int,
 ):
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ik = pl.program_id(1)
+    nk = pl.num_programs(1)
     kv_len = kv_len_ref[0]
 
     @pl.when(ik == 0)
@@ -56,33 +59,36 @@ def _decode_kernel(
     # skip blocks entirely beyond the valid cache length
     @pl.when(ik * block_k < kv_len)
     def _compute():
-        G, D = q_ref.shape[2], q_ref.shape[3]
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
-        k = k_ref[:, :, 0, :][0].astype(jnp.float32)  # (block_k, D)
-        v = v_ref[:, :, 0, :][0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (G, block_k)
+        KVH, G = q_ref.shape[1], q_ref.shape[2]
         kv_pos = ik * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (G, block_k), 1
         )
-        s = jnp.where(kv_pos < kv_len, s, NEG_INF)
+        # the block carries every KV head of the stripe, so the cache is read
+        # in its stored (B, S, KVH, D) layout; heads are a static loop
+        for h in range(KVH):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (block_k, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )  # (G, block_k)
+            s = jnp.where(kv_pos < kv_len, s, NEG_INF)
 
-        m_prev = m[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l[...] = jnp.broadcast_to(
-            l[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True), l.shape
-        )
-        acc[...] = acc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m[...] = jnp.broadcast_to(m_new, m.shape)
+            m_prev = m[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l[h] = jnp.broadcast_to(
+                l[h][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True), (G, LANES)
+            )
+            acc[h] = acc[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            m[h] = jnp.broadcast_to(m_new, (G, LANES))
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / jnp.maximum(l[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
 def decode_attention(
@@ -110,17 +116,17 @@ def decode_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, KVH, nk),
+        grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, *_: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, *_: (b, ik, h, 0)),
+            pl.BlockSpec((1, KVH, G, D), lambda b, ik, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, block_k, KVH, D), lambda b, ik, *_: (b, ik, 0, 0)),
+            pl.BlockSpec((1, block_k, KVH, D), lambda b, ik, *_: (b, ik, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KVH, G, D), lambda b, ik, *_: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, LANES), jnp.float32),
-            pltpu.VMEM((G, LANES), jnp.float32),
+            pltpu.VMEM((KVH, G, D), jnp.float32),
+            pltpu.VMEM((KVH, G, LANES), jnp.float32),
+            pltpu.VMEM((KVH, G, LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -13,13 +13,17 @@ softmax recurrence is re-tiled for the MXU and VMEM:
     sweep of each q tile — the HBM traffic is exactly one read of q/k/v and
     one write of o per tile;
   * softmax stats are kept as (rows, 128) lane-replicated tiles (VREG-
-    friendly broadcast instead of (rows, 1) relayouts);
+    friendly broadcast instead of (rows, 1) relayouts); the saved lse is
+    lane-replicated in HBM too, because Mosaic cannot turn a lane-dense
+    (Bq, G) tile back into the (rows, 1) column the backward needs;
   * causal q-tiles skip fully-masked KV tiles via ``pl.when`` on the grid
     index (≈2x fewer MXU ops at long seq).
 
 Backward follows the two-kernel FlashAttention-2 schedule: a dk/dv kernel
 with the q dim innermost, and a dq kernel with the KV dim innermost; both
-recompute p from (q, k, lse) so no S x S tensor ever exists.
+recompute p from (q, k, lse) so no S x S tensor ever exists, and both
+recompute the row term delta = sum(o * do) from their (o, do) tiles, which
+yields it directly as a (rows, 1) column.
 
 Validated against ``ref.mha_reference`` in interpret mode (tests/test_kernels.py).
 """
@@ -35,6 +39,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # stat tiles are lane-replicated to this width
+# At block 512 with G=4 folded rows the backward kernels hold several
+# (2048, 512) f32 tiles at once, just over Mosaic's 16 MiB default scoped
+# VMEM; v5e has 128 MiB of VMEM per core.
+_BWD_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20)
 
 
 def _row_positions(block_q: int, g: int, iq, q_offset: int):
@@ -54,7 +62,7 @@ def _fwd_kernel(
     k_ref,  # (1, 1, Bk, D)
     v_ref,  # (1, 1, Bk, D)
     o_ref,  # (1, 1, Bq, G, D)
-    lse_ref,  # (1, 1, Bq, G)
+    lse_ref,  # (1, 1, Bq*G, LANES) lane-replicated
     acc,  # VMEM (Bq*G, D) f32
     m,  # VMEM (Bq*G, LANES) f32
     l,  # VMEM (Bq*G, LANES) f32
@@ -116,10 +124,7 @@ def _fwd_kernel(
         lsum = l[:, :1]
         out = acc[...] / jnp.maximum(lsum, 1e-30)
         o_ref[0, 0] = out.reshape(o_ref.shape[2:]).astype(o_ref.dtype)
-        lse = (m[:, :1] + jnp.log(jnp.maximum(lsum, 1e-30))).reshape(
-            block_q, g
-        )
-        lse_ref[0, 0] = lse
+        lse_ref[0, 0] = m[...] + jnp.log(jnp.maximum(l[...], 1e-30))
 
 
 def flash_attention_fwd(
@@ -134,7 +139,11 @@ def flash_attention_fwd(
     q_offset: int = 0,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (o (B,KVH,Sq,G,D), lse (B,KVH,Sq,G) f32)."""
+    """Returns (o (B,KVH,Sq,G,D), lse (B,KVH,Sq*G,LANES) f32).
+
+    Row ``t*G + g`` of lse belongs to query ``t``, head ``g`` of the group;
+    its LANES columns hold the same value.
+    """
     B, KVH, Sq, G, D = q.shape
     Skv = k.shape[2]
     block_q = min(block_q, Sq)
@@ -166,11 +175,11 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, G, D), lambda b, h, iq, ik: (b, h, iq, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, G), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, rows, LANES), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, KVH, Sq, G, D), q.dtype),
-            jax.ShapeDtypeStruct((B, KVH, Sq, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, KVH, Sq * G, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((rows, D), jnp.float32),
@@ -186,70 +195,71 @@ def flash_attention_fwd(
 # ---------------------------------------------------------------------------
 
 
+def _bwd_tile(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *, scale, causal,
+              block_q, block_k, g, kv_valid, q_offset, iq, ik):
+    """Recompute one (q tile, kv tile) pair: returns (q, k, do, p, ds)."""
+    rows = block_q * g
+    D = q_ref.shape[-1]
+    q = q_ref[0, 0].reshape(rows, D).astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    do = do_ref[0, 0].reshape(rows, D).astype(jnp.float32)
+    o = o_ref[0, 0].reshape(rows, D).astype(jnp.float32)
+    lse = lse_ref[0, 0][:, :1]  # (rows, 1)
+    delta = jnp.sum(o * do, axis=-1, keepdims=True)  # (rows, 1)
+
+    s = jax.lax.dot_general(
+        q * scale, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    kv_pos = ik * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, block_k), 1
+    )
+    mask = kv_pos < kv_valid
+    if causal:
+        mask &= _row_positions(block_q, g, iq, q_offset) >= kv_pos
+    s = jnp.where(mask, s, NEG_INF)
+    p = jnp.exp(s - lse)  # (rows, Bk) — true softmax probs
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return q, k, do, p, p * (dp - delta)
+
+
 def _bwd_dkv_kernel(
     q_ref,  # (1, 1, Bq, G, D)
     k_ref,  # (1, 1, Bk, D)
     v_ref,  # (1, 1, Bk, D)
+    o_ref,  # (1, 1, Bq, G, D)
     do_ref,  # (1, 1, Bq, G, D)
-    lse_ref,  # (1, 1, Bq, G)
-    delta_ref,  # (1, 1, Bq, G)
+    lse_ref,  # (1, 1, Bq*G, LANES)
     dk_ref,  # (1, 1, Bk, D)
     dv_ref,  # (1, 1, Bk, D)
     dk_acc,  # VMEM (Bk, D) f32
     dv_acc,  # VMEM (Bk, D) f32
-    *,
-    scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    g: int,
-    kv_valid: int,
-    q_offset: int,
+    **common,
 ):
     ik, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
-    rows = block_q * g
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_hi = q_offset + (iq + 1) * block_q - 1
-    live = (ik * block_k <= q_hi) if causal else (ik >= 0)
+    q_hi = common["q_offset"] + (iq + 1) * common["block_q"] - 1
+    live = (ik * common["block_k"] <= q_hi) if common["causal"] else (ik >= 0)
 
     @pl.when(live)
     def _compute():
-        D = q_ref.shape[-1]
-        q = q_ref[0, 0].reshape(rows, D).astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].reshape(rows, D).astype(jnp.float32)
-        lse = lse_ref[0, 0].reshape(rows, 1)
-        delta = delta_ref[0, 0].reshape(rows, 1)
-
-        s = jax.lax.dot_general(
-            q * scale, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        q, _, do, p, ds = _bwd_tile(
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, iq=iq, ik=ik, **common
         )
-        kv_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_k), 1
-        )
-        mask = kv_pos < kv_valid
-        if causal:
-            mask &= _row_positions(block_q, g, iq, q_offset) >= kv_pos
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)  # (rows, Bk) — true softmax probs
-        # dv += p^T @ do
+        # dv += p^T @ do ; dk += ds^T @ q * scale
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        # ds = p * (do @ v^T - delta); dk += ds^T @ q * scale
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dk_acc[...] += scale * jax.lax.dot_general(
+        dk_acc[...] += common["scale"] * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
@@ -263,58 +273,29 @@ def _bwd_dq_kernel(
     q_ref,  # (1, 1, Bq, G, D)
     k_ref,  # (1, 1, Bk, D)
     v_ref,  # (1, 1, Bk, D)
+    o_ref,  # (1, 1, Bq, G, D)
     do_ref,  # (1, 1, Bq, G, D)
-    lse_ref,  # (1, 1, Bq, G)
-    delta_ref,  # (1, 1, Bq, G)
+    lse_ref,  # (1, 1, Bq*G, LANES)
     dq_ref,  # (1, 1, Bq, G, D)
     dq_acc,  # VMEM (Bq*G, D) f32
-    *,
-    scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    g: int,
-    kv_valid: int,
-    q_offset: int,
+    **common,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
-    rows = block_q * g
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_hi = q_offset + (iq + 1) * block_q - 1
-    live = (ik * block_k <= q_hi) if causal else (ik >= 0)
+    q_hi = common["q_offset"] + (iq + 1) * common["block_q"] - 1
+    live = (ik * common["block_k"] <= q_hi) if common["causal"] else (ik >= 0)
 
     @pl.when(live)
     def _compute():
-        D = q_ref.shape[-1]
-        q = q_ref[0, 0].reshape(rows, D).astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].reshape(rows, D).astype(jnp.float32)
-        lse = lse_ref[0, 0].reshape(rows, 1)
-        delta = delta_ref[0, 0].reshape(rows, 1)
-
-        s = jax.lax.dot_general(
-            q * scale, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        _, k, _, _, ds = _bwd_tile(
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, iq=iq, ik=ik, **common
         )
-        kv_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_k), 1
-        )
-        mask = kv_pos < kv_valid
-        if causal:
-            mask &= _row_positions(block_q, g, iq, q_offset) >= kv_pos
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dq_acc[...] += scale * jax.lax.dot_general(
+        dq_acc[...] += common["scale"] * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
@@ -340,30 +321,30 @@ def flash_attention_bwd(
     block_k = min(block_k, Skv)
     nq, nk = Sq // block_q, Skv // block_k
 
-    # delta[b,h,t,g] = sum_d do * o — the rowwise correction term
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-
     common = dict(
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         g=G, kv_valid=Skv, q_offset=q_offset,
     )
     rows = block_q * G
+    # index maps take (b, h, outer, inner); q_of/kv_of pick the tile index
+    q_spec = lambda q_of: pl.BlockSpec(
+        (1, 1, block_q, G, D), lambda b, h, i, j: (b, h, q_of(i, j), 0, 0))
+    kv_spec = lambda kv_of: pl.BlockSpec(
+        (1, 1, block_k, D), lambda b, h, i, j: (b, h, kv_of(i, j), 0))
+    lse_spec = lambda q_of: pl.BlockSpec(
+        (1, 1, rows, LANES), lambda b, h, i, j: (b, h, q_of(i, j), 0))
+    outer = lambda i, j: i
+    inner = lambda i, j: j
 
+    # dk/dv: grid (B, KVH, nk, nq) — kv tile outer, q tile inner
     dkv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         grid=(B, KVH, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, G, D), lambda b, h, ik, iq: (b, h, iq, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_q, G, D), lambda b, h, ik, iq: (b, h, iq, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, G), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, G), lambda b, h, ik, iq: (b, h, iq, 0)),
+            q_spec(inner), kv_spec(outer), kv_spec(outer),
+            q_spec(inner), q_spec(inner), lse_spec(inner),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-        ],
+        out_specs=[kv_spec(outer), kv_spec(outer)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -372,26 +353,23 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        compiler_params=_BWD_PARAMS,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, o, do, lse)
 
+    # dq: grid (B, KVH, nq, nk) — q tile outer, kv tile inner
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(B, KVH, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, G, D), lambda b, h, iq, ik: (b, h, iq, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_q, G, D), lambda b, h, iq, ik: (b, h, iq, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, G), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, G), lambda b, h, iq, ik: (b, h, iq, 0)),
+            q_spec(outer), kv_spec(inner), kv_spec(inner),
+            q_spec(outer), q_spec(outer), lse_spec(outer),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, G, D), lambda b, h, iq, ik: (b, h, iq, 0, 0)),
-        ],
+        out_specs=[q_spec(outer)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)],
+        compiler_params=_BWD_PARAMS,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)[0]
+    )(q, k, v, o, do, lse)[0]
 
     return dq, dkv[0], dkv[1]

@@ -35,13 +35,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _wkv6_kernel(
-    r_ref,  # (1, C, 1, K)
-    k_ref,  # (1, C, 1, K)
-    v_ref,  # (1, C, 1, V)
-    w_ref,  # (1, C, 1, K) log-decay <= 0
-    u_ref,  # (1, K)
+    r_ref,  # (1, 1, C, K)
+    k_ref,  # (1, 1, C, K)
+    v_ref,  # (1, 1, C, V)
+    w_ref,  # (1, 1, C, K) log-decay <= 0
+    u_ref,  # (1, 1, K)
     s0_ref,  # (1, 1, K, V) initial state
-    o_ref,  # (1, C, 1, V)
+    o_ref,  # (1, 1, C, V)
     sT_ref,  # (1, 1, K, V) final state
     S,  # VMEM (K, V) f32 carried state
     *,
@@ -55,21 +55,28 @@ def _wkv6_kernel(
         S[...] = s0_ref[0, 0].astype(jnp.float32)
 
     C = chunk
-    r = r_ref[0, :, 0, :].astype(jnp.float32)  # (C, K)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (C, V)
-    w = w_ref[0, :, 0, :].astype(jnp.float32)  # (C, K), <= 0
-    u = u_ref[0].astype(jnp.float32)  # (K,)
+    r = r_ref[0, 0].astype(jnp.float32)  # (C, K)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)  # (C, V)
+    w = w_ref[0, 0].astype(jnp.float32)  # (C, K), <= 0
+    u = u_ref[0, 0].astype(jnp.float32)  # (K,)
 
-    clw = jnp.cumsum(w, axis=0)  # inclusive cumulative log-decay
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    # inclusive cumulative log-decay as a lower-triangular matmul: Mosaic
+    # has no cumsum lowering
+    clw = jax.lax.dot_general(
+        (t_idx >= s_idx).astype(jnp.float32), w, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
     clw_ex = clw - w  # exclusive
 
     # pairwise decay for s < t: exp(clw_ex[t] - clw[s]) (<= 0 exponent)
     diff = clw_ex[:, None, :] - clw[None, :, :]  # (C, C, K)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    strict = t_idx > s_idx  # strictly lower triangular
-    decay = jnp.exp(jnp.where(strict[:, :, None], diff, -jnp.inf))  # (C,C,K)
+    # the mask is built at rank 3: Mosaic cannot append a lane dim to (C, C)
+    t3 = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0)
+    s3 = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1)
+    decay = jnp.exp(jnp.where(t3 > s3, diff, -jnp.inf))  # (C,C,K)
 
     # scores[t,s] = sum_k r[t,k] k[s,k] decay[t,s,k]
     scores = jnp.sum(r[:, None, :] * k[None, :, :] * decay, axis=-1)  # (C,C)
@@ -83,7 +90,7 @@ def _wkv6_kernel(
         r * jnp.exp(clw_ex), S[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
     # state update: S' = exp(clw[-1]) * S + sum_s (k_s e^{clw[-1]-clw[s]}) v_s^T
     last = clw[-1:, :]  # (1, K)
@@ -116,9 +123,12 @@ def wkv6_scan(
         raise ValueError(f"T={T} must be divisible by chunk={chunk}")
     nc = T // chunk
 
+    # head-major (B, H, T, K): a (chunk, K) block per head fits the TPU's
+    # (8, 128) tiling, where a one-head slab of (B, T, H, K) would not
+    r, k, v, logw = (x.transpose(0, 2, 1, 3) for x in (r, k, v, logw))
     grid = (B, H, nc)
-    seq_spec_k = pl.BlockSpec((1, chunk, 1, K), lambda b, h, ic: (b, ic, h, 0))
-    seq_spec_v = pl.BlockSpec((1, chunk, 1, V), lambda b, h, ic: (b, ic, h, 0))
+    seq_spec_k = pl.BlockSpec((1, 1, chunk, K), lambda b, h, ic: (b, h, ic, 0))
+    seq_spec_v = pl.BlockSpec((1, 1, chunk, V), lambda b, h, ic: (b, h, ic, 0))
     state_spec = pl.BlockSpec((1, 1, K, V), lambda b, h, ic: (b, h, 0, 0))
 
     out, state = pl.pallas_call(
@@ -129,15 +139,15 @@ def wkv6_scan(
             seq_spec_k,
             seq_spec_v,
             seq_spec_k,
-            pl.BlockSpec((1, K), lambda b, h, ic: (h, 0)),
+            pl.BlockSpec((1, 1, K), lambda b, h, ic: (h, 0, 0)),
             state_spec,
         ],
         out_specs=[seq_spec_v, state_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T, H, V), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, T, V), jnp.float32),
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u, state0)
-    return out, state
+    )(r, k, v, logw, u.reshape(H, 1, K), state0)
+    return out.transpose(0, 2, 1, 3), state

@@ -4,24 +4,29 @@ The production entry point: build the model from ``--arch``, shard it over
 the chosen mesh, stream deterministic synthetic data through the host
 pipeline, checkpoint every ``--ckpt-every`` steps (async, atomic), resume
 automatically from the latest valid checkpoint, and log step time / loss /
-input-wait. On this CPU container use ``--reduced`` for a runnable config;
-on a pod the same flags drive the full config.
+input-wait. On a CPU host use ``--reduced`` for a runnable config; on a
+TPU the same flags drive the published widths, with ``--layers`` cutting
+depth to fit one chip.
 
   PYTHONPATH=src python -m repro.launch.train --arch granite-3-2b --reduced \
       --steps 50 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+
+The step is compiled ahead of the loop, so ``compile_s`` in the result is
+set-up time and ``mean_step_ms`` (steps after the first three, each timed up
+to a device sync) is steady state.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
+import os
 import time
 from pathlib import Path
 
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs.base import ShapeSuite
 from repro.configs.registry import get_config
@@ -30,14 +35,34 @@ from repro.data import synthetic
 from repro.data.pipeline import HostPipeline
 from repro.models.model_api import build_model
 from repro.optim import adamw
+from repro.launch.mesh import make_mesh_shape
 from repro.runtime import train_step as ts
 from repro.sharding.plan import make_plan
+
+# fixed, inside the checkout: the cache key includes the directory, so a
+# per-process or temporary path would never hit
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins when it is set (JAX reads it itself);
+    otherwise the cache lives in ``.jax_cache/`` at the checkout root.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_COMPILE_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def build_argparser():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true", help="CPU-scale config")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut depth to N layers at published widths (0: keep)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -65,13 +90,17 @@ def make_host_mesh():
     if n == 1:
         return None
     rows = max(1, n // 2)
-    return jax.make_mesh((rows, n // rows), ("data", "model"))
+    return make_mesh_shape((rows, n // rows), ("data", "model"))
 
 
 def run(args) -> dict:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers:
+        if cfg.family == "resnet":
+            raise ValueError("--layers cuts stacked layers; resnet depth is its stages")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     suite = ShapeSuite("train_cli", args.seq, args.batch, "train")
     model = build_model(cfg)
     opt_cfg = adamw.AdamWConfig(
@@ -80,6 +109,7 @@ def run(args) -> dict:
     )
 
     mesh = make_host_mesh() if args.mesh == "host" else None
+    st_sh = b_sh = None
     if mesh is not None:
         jitted, st_sh, b_sh, plan = ts.jit_train_step(
             model, mesh, suite, opt_cfg, grad_accum=args.grad_accum
@@ -100,6 +130,13 @@ def run(args) -> dict:
             state, extra = store.restore(state, latest)
             start_step = latest
             print(f"[train] resumed from step {latest}", flush=True)
+    if st_sh is not None:
+        state = jax.device_put(state, st_sh)
+
+    t_compile0 = time.perf_counter()
+    compiled = jitted.lower(state, model.input_specs(suite)).compile()
+    compile_s = time.perf_counter() - t_compile0
+    hlo = compiled.as_text()
 
     pipeline = HostPipeline(
         lambda step: synthetic.batch_for(cfg, suite, seed=args.seed, step=step),
@@ -113,14 +150,14 @@ def run(args) -> dict:
     t_train0 = time.perf_counter()
     try:
         for step in range(start_step, args.steps):
-            batch = {k: jnp.asarray(v) for k, v in pipeline.get().items()}
+            batch = jax.device_put(pipeline.get(), b_sh)
             t0 = time.perf_counter()
-            state, metrics = jitted(state, batch)
-            loss = float(metrics["loss"])
+            state, metrics = jax.block_until_ready(compiled(state, batch))
             step_times.append(time.perf_counter() - t0)
+            loss = float(metrics["loss"])
             losses.append(loss)
-            if np.isnan(loss):
-                raise FloatingPointError(f"NaN loss at step {step}")
+            if not np.isfinite(loss):
+                raise FloatingPointError(f"non-finite loss {loss} at step {step}")
             if (step + 1) % args.log_every == 0:
                 print(
                     f"[train] step {step + 1}/{args.steps} loss={loss:.4f} "
@@ -136,9 +173,15 @@ def run(args) -> dict:
         store.wait()
 
     wall = time.perf_counter() - t_train0
+    dev = jax.devices()[0]
+    mem = dev.memory_stats() or {}
     result = {
         "arch": args.arch,
+        "layers": cfg.n_layers,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "steps": args.steps - start_step,
+        "losses": losses,
         "final_loss": losses[-1] if losses else None,
         "first_loss": losses[0] if losses else None,
         # window means: single-step losses on stochastic batches are too
@@ -146,6 +189,10 @@ def run(args) -> dict:
         "head_mean_loss": float(np.mean(losses[:5])) if losses else None,
         "tail_mean_loss": float(np.mean(losses[-5:])) if losses else None,
         "mean_step_ms": float(np.mean(step_times[3:]) * 1e3) if len(step_times) > 3 else None,
+        "compile_s": compile_s,
+        # Pallas kernels in the compiled step (0 on the XLA path)
+        "tpu_custom_calls": hlo.count('custom_call_target="tpu_custom_call"'),
+        "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
         "wall_s": wall,
         "pipeline": pipeline.stats(),
     }
@@ -156,6 +203,7 @@ def run(args) -> dict:
 
 def main():
     args = build_argparser().parse_args()
+    use_compile_cache()
     result = run(args)
     print(json.dumps(result, indent=2))
 

@@ -14,6 +14,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -32,6 +33,12 @@ def _gqa_values(p, v):
     )
 
 
+def _kernel_mode() -> Optional[str]:
+    """Pallas execution mode for the training path: compiled on a TPU backend,
+    none (the XLA path) elsewhere."""
+    return "tpu" if jax.default_backend() == "tpu" else None
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -42,15 +49,19 @@ def flash_attention(
     q_offset: int = 0,
     scale: Optional[float] = None,
     kv_len: Optional[jax.Array] = None,
+    plan=None,
 ) -> jax.Array:
     """Backend dispatch: Pallas kernel on TPU, XLA scan path elsewhere.
 
     The XLA path is what the 512-placeholder-device dry-run lowers (identical
     math, no Mosaic dependency); on a real TPU the Pallas kernel from
     ``repro.kernels`` takes over. kv_len/q_offset users (decode) stay XLA.
+    Under a mesh (``plan.mesh``) the kernel runs per shard inside
+    ``shard_map``: XLA cannot partition a Mosaic custom call itself.
     """
+    mode = _kernel_mode()
     if (
-        jax.default_backend() == "tpu"
+        mode is not None
         and kv_len is None
         and q_offset == 0
         and q.shape[1] % 512 == 0
@@ -58,9 +69,21 @@ def flash_attention(
     ):
         from repro.kernels import ops
 
-        return ops.flash_attention(
-            q, k, v, causal=causal, scale=scale, mode="tpu"
-        )
+        def kernel(q, k, v):
+            return ops.flash_attention(q, k, v, causal=causal, scale=scale, mode=mode)
+
+        if plan is None or plan.mesh is None:
+            return kernel(q, k, v)
+        q_spec, kv_spec = plan.spec("heads"), plan.spec("kv_heads")
+        if q_spec[2] != kv_spec[2]:
+            # heads split over the model axis only when q and kv heads both
+            # divide it, so each shard keeps whole GQA groups
+            q_spec = P(q_spec[0], None, None, None)
+            kv_spec = P(kv_spec[0], None, None, None)
+        return jax.shard_map(
+            kernel, mesh=plan.mesh, in_specs=(q_spec, kv_spec, kv_spec),
+            out_specs=q_spec, check_vma=False,
+        )(q, k, v)
     return xla_flash_attention(
         q, k, v, causal=causal, block_k=block_k, q_offset=q_offset,
         scale=scale, kv_len=kv_len,

@@ -149,7 +149,9 @@ def encode(cfg: ModelConfig, params: Params, frames: jax.Array, plan: ShardingPl
     def body(x, lp):
         xn = nn.layernorm_apply(lp["attn_norm"], x)
         q, k, v = _mha_qkv(cfg, lp["attn"], xn, xn, plan)
-        out = xla_flash_attention(q, k, v, causal=False, block_k=cfg.attn_block_k)
+        out = xla_flash_attention(
+            q, k, v, causal=False, block_k=cfg.attn_block_k, plan=plan
+        )
         x = x + _mha_out(lp["attn"], out, B, T)
         x = x + _mlp(lp["mlp"], nn.layernorm_apply(lp["mlp_norm"], x))
         return plan.act(x, "frames")
@@ -161,11 +163,13 @@ def encode(cfg: ModelConfig, params: Params, frames: jax.Array, plan: ShardingPl
 def _dec_block(cfg, plan, enc_out, B, S, x, lp, positions):
     xn = nn.layernorm_apply(lp["self_norm"], x)
     q, k, v = _mha_qkv(cfg, lp["self_attn"], xn, xn, plan)
-    out = xla_flash_attention(q, k, v, causal=True, block_k=cfg.attn_block_k)
+    out = xla_flash_attention(q, k, v, causal=True, block_k=cfg.attn_block_k, plan=plan)
     x = x + _mha_out(lp["self_attn"], out, B, S)
     xn = nn.layernorm_apply(lp["cross_norm"], x)
     q, k, v = _mha_qkv(cfg, lp["cross_attn"], xn, enc_out, plan)
-    out = xla_flash_attention(q, k, v, causal=False, block_k=cfg.attn_block_k)
+    out = xla_flash_attention(
+        q, k, v, causal=False, block_k=cfg.attn_block_k, plan=plan
+    )
     x = x + _mha_out(lp["cross_attn"], out, B, S)
     x = x + _mlp(lp["mlp"], nn.layernorm_apply(lp["mlp_norm"], x))
     return plan.act(x, "hidden")
@@ -223,11 +227,15 @@ def prefill(cfg: ModelConfig, params: Params, frames, tokens, plan: ShardingPlan
     def body(x, lp):
         xn = nn.layernorm_apply(lp["self_norm"], x)
         q, k, v = _mha_qkv(cfg, lp["self_attn"], xn, xn, plan)
-        out = xla_flash_attention(q, k, v, causal=True, block_k=cfg.attn_block_k)
+        out = xla_flash_attention(
+            q, k, v, causal=True, block_k=cfg.attn_block_k, plan=plan
+        )
         x = x + _mha_out(lp["self_attn"], out, B, S)
         xn = nn.layernorm_apply(lp["cross_norm"], x)
         qx, xk, xv = _mha_qkv(cfg, lp["cross_attn"], xn, enc_out, plan)
-        out = xla_flash_attention(qx, xk, xv, causal=False, block_k=cfg.attn_block_k)
+        out = xla_flash_attention(
+            qx, xk, xv, causal=False, block_k=cfg.attn_block_k, plan=plan
+        )
         x = x + _mha_out(lp["cross_attn"], out, B, S)
         x = x + _mlp(lp["mlp"], nn.layernorm_apply(lp["mlp_norm"], x))
         x = plan.act(x, "hidden")

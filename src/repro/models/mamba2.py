@@ -288,7 +288,9 @@ def _attn_prefill_block(cfg, lp, x, plan, positions):
     q, k, v = tfm._qkv(cfg, lp["attn"], xn, plan)
     q = nn.apply_rope(q, positions, cfg.rope_theta)
     kr = nn.apply_rope(k, positions, cfg.rope_theta)
-    out = tfm.xla_flash_attention(q, kr, v, causal=True, block_k=cfg.attn_block_k)
+    out = tfm.xla_flash_attention(
+        q, kr, v, causal=True, block_k=cfg.attn_block_k, plan=plan
+    )
     x = x + nn.dense_apply({"w": lp["attn"]["wo"]}, out.reshape(B, S, -1))
     x = x + tfm._mlp(cfg, lp["mlp"], tfm._norm(cfg, lp["mlp_norm"], x), plan)
     return plan.act(x, "hidden"), kr.astype(jnp.bfloat16), v.astype(jnp.bfloat16)

@@ -287,7 +287,9 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array, plan: ShardingP
         q, k, v = tfm._qkv(cfg, lp["attn"], xn, plan)
         q = nn.apply_rope(q, positions, cfg.rope_theta)
         kr = nn.apply_rope(k, positions, cfg.rope_theta)
-        out = tfm.xla_flash_attention(q, kr, v, causal=True, block_k=cfg.attn_block_k)
+        out = tfm.xla_flash_attention(
+            q, kr, v, causal=True, block_k=cfg.attn_block_k, plan=plan
+        )
         x = x + nn.dense_apply({"w": lp["attn"]["wo"]}, out.reshape(B, S, -1))
         y, _ = moe_ffn(cfg, lp["moe"], tfm._norm(cfg, lp["mlp_norm"], x), plan)
         x = plan.act(x + y, "hidden")

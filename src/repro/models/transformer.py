@@ -139,7 +139,9 @@ def _attn_train(
     positions = jnp.arange(S)
     q = nn.apply_rope(q, positions, cfg.rope_theta)
     k = nn.apply_rope(k, positions, cfg.rope_theta)
-    out = xla_flash_attention(q, k, v, causal=causal, block_k=cfg.attn_block_k)
+    out = xla_flash_attention(
+        q, k, v, causal=causal, block_k=cfg.attn_block_k, plan=plan
+    )
     out = plan.act(out, "heads")
     return nn.dense_apply({"w": p["wo"]}, out.reshape(B, S, -1))
 
@@ -254,7 +256,9 @@ def prefill(
         q, k, v = _qkv(cfg, lp["attn"], xn, plan)
         q = nn.apply_rope(q, positions, cfg.rope_theta)
         kr = nn.apply_rope(k, positions, cfg.rope_theta)
-        out = xla_flash_attention(q, kr, v, causal=True, block_k=cfg.attn_block_k)
+        out = xla_flash_attention(
+            q, kr, v, causal=True, block_k=cfg.attn_block_k, plan=plan
+        )
         x = x + nn.dense_apply({"w": lp["attn"]["wo"]}, out.reshape(B, S, -1))
         x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["mlp_norm"], x), plan)
         x = plan.act(x, "hidden")

@@ -22,7 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import module as nn
 from repro.models import transformer as tfm
-from repro.runtime.compat import shard_map
 from repro.sharding.plan import ShardingPlan
 
 
@@ -110,7 +109,7 @@ def pipeline_forward(
     if cfg.tie_embeddings:
         head = {"embed": params["embed"]}
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(

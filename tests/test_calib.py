@@ -14,6 +14,7 @@ import pytest
 from repro.core.calib import (
     CharDB,
     CharRecord,
+    KernelBackend,
     OnlineCalibrator,
     StubBackend,
     calibration_report,
@@ -303,3 +304,14 @@ def test_calibrate_cli_writes_deterministic_artifacts(tmp_path):
     # the written DB is a valid versioned document that loads back
     db = CharDB.loads((out1 / "calib_db__a100-40gb.json").read_text())
     assert db.sku == "a100-40gb" and len(db) == 40
+
+
+def test_kernel_backend_times_compiled_kernels_on_tpu(monkeypatch):
+    """The measured path times the interpreter only off the chip: on a TPU
+    backend it asks kernel_bench for the compiled Pallas kernels."""
+    import jax
+
+    assert jax.default_backend() == "cpu"
+    assert KernelBackend.kernel_mode() == "interpret"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert KernelBackend.kernel_mode() == "tpu"

@@ -437,7 +437,8 @@ def test_cli_unknown_gang_world_size_errors_with_choices(capsys):
     with pytest.raises(SystemExit) as e:
         simulate_main(["--gang-world-size", "3"])
     assert e.value.code == 2
-    assert "invalid choice: 3" in capsys.readouterr().err
+    # Python 3.12 quotes the rejected value: invalid choice: '3'
+    assert "invalid choice: '3'" in capsys.readouterr().err
 
 
 def test_cli_mismatched_world_size_lists_registered_descriptors(capsys):

@@ -89,7 +89,12 @@ def test_flash_lse_is_true_logsumexp():
     mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
     scores = jnp.where(mask[None, None, :, None, :], scores, -1e30)
     want = jax.scipy.special.logsumexp(scores, axis=-1)  # (B,H,S,G)
-    np.testing.assert_allclose(lse, want.transpose(0, 1, 2, 3), atol=1e-4, rtol=1e-4)
+    # lse rows are (query, group head) folded, each row lane-replicated
+    assert lse.shape == (B, KVH, S * (H // KVH), fa.LANES)
+    np.testing.assert_array_equal(lse, jnp.broadcast_to(lse[..., :1], lse.shape))
+    np.testing.assert_allclose(
+        lse[..., 0].reshape(B, KVH, S, H // KVH), want, atol=1e-4, rtol=1e-4
+    )
 
 
 # ---------------------------------------------------------------------------

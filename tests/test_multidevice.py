@@ -35,6 +35,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.runtime import train_step as ts
         from repro.sharding.plan import make_plan
         from repro.data import synthetic
+        from repro.launch.mesh import make_mesh_shape
 
         cfg = get_config("granite-3-2b").reduced()
         suite = ShapeSuite("t", 32, 8, "train")
@@ -51,7 +52,7 @@ def test_sharded_train_step_matches_single_device():
         st0, m0b = step0(st0, batch)
 
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh_shape((2, 4), ("data", "model"))
         jitted, st_sh, b_sh, plan = ts.jit_train_step(model, mesh, suite, opt)
         st = ts.init_train_state(model, jax.random.key(0), opt)
         st = jax.device_put(st, st_sh)

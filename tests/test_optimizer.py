@@ -65,16 +65,16 @@ def test_gradient_compression_error_feedback():
 
     from repro.optim import compression
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    from repro.launch.mesh import make_mesh_shape
+
+    mesh = make_mesh_shape((1,), ("pod",))
     g = {"w": jax.random.normal(jax.random.key(0), (256,)) * 0.1}
     e0 = compression.init_error_state(g)
 
     def body(g, e):
         return compression.ef_int8_psum(g, e, "pod")
 
-    from repro.runtime.compat import shard_map
-
-    mean, err = shard_map(
+    mean, err = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False,
     )(g, e0)

@@ -31,10 +31,10 @@ def test_ring_matmuls_match_oracles():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.compat import shard_map
+        from repro.launch.mesh import make_mesh_shape
         from repro.runtime.ring import ring_ag_matmul, ring_rs_matmul
 
-        mesh = jax.make_mesh((4,), ("m",))
+        mesh = make_mesh_shape((4,), ("m",))
         B, d, f = 8, 16, 32  # f_local = f // 4
         x = jax.random.normal(jax.random.key(0), (B, d))
         w = jax.random.normal(jax.random.key(1), (d, f))
@@ -42,7 +42,7 @@ def test_ring_matmuls_match_oracles():
         def ag(xl, wl):
             return ring_ag_matmul(xl, wl, "m")
 
-        y = shard_map(ag, mesh=mesh, in_specs=(P("m", None), P(None, "m")),
+        y = jax.shard_map(ag, mesh=mesh, in_specs=(P("m", None), P(None, "m")),
                       out_specs=P("m", None), check_vma=False)(x, w)
         np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w),
                                    rtol=2e-5, atol=1e-5)
@@ -54,7 +54,7 @@ def test_ring_matmuls_match_oracles():
         def rs(xl, wl):
             return ring_rs_matmul(xl, wl, "m")
 
-        y2 = shard_map(rs, mesh=mesh, in_specs=(P("m", None), P("m", None)),
+        y2 = jax.shard_map(rs, mesh=mesh, in_specs=(P("m", None), P("m", None)),
                        out_specs=P("m", None), check_vma=False)(x2, w2)
         np.testing.assert_allclose(np.asarray(y2), np.asarray(x2 @ w2),
                                    rtol=2e-5, atol=1e-5)
@@ -69,6 +69,7 @@ def test_gpipe_pipeline_matches_plain_forward():
         from repro.configs.registry import get_config
         from repro.models.model_api import build_model
         from repro.models import transformer as tfm
+        from repro.launch.mesh import make_mesh_shape
         from repro.runtime.pipeline import pipeline_forward
         from repro.sharding.plan import make_plan
 
@@ -80,7 +81,7 @@ def test_gpipe_pipeline_matches_plain_forward():
         toks = jax.random.randint(jax.random.key(1), (M, mb, S), 0, cfg.vocab, jnp.int32)
 
         ref = tfm.forward(cfg, params, toks.reshape(M * mb, S), plan)
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh_shape((4,), ("stage",))
         got = pipeline_forward(cfg, params, toks, mesh)
         got = got.reshape(M * mb, S, -1)
         err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))))
@@ -97,21 +98,21 @@ def test_ring_matmuls_world_size_one_degenerate():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.compat import shard_map
+        from repro.launch.mesh import make_mesh_shape
         from repro.runtime.ring import ring_ag_matmul, ring_rs_matmul
 
-        mesh = jax.make_mesh((1,), ("m",))
+        mesh = make_mesh_shape((1,), ("m",))
         B, d, f = 4, 8, 16
         x = jax.random.normal(jax.random.key(0), (B, d))
         w = jax.random.normal(jax.random.key(1), (d, f))
-        y = shard_map(lambda xl, wl: ring_ag_matmul(xl, wl, "m"), mesh=mesh,
+        y = jax.shard_map(lambda xl, wl: ring_ag_matmul(xl, wl, "m"), mesh=mesh,
                       in_specs=(P("m", None), P(None, "m")),
                       out_specs=P("m", None), check_vma=False)(x, w)
         np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w),
                                    rtol=2e-5, atol=1e-5)
         x2 = jax.random.normal(jax.random.key(2), (B, f))
         w2 = jax.random.normal(jax.random.key(3), (f, d))
-        y2 = shard_map(lambda xl, wl: ring_rs_matmul(xl, wl, "m"), mesh=mesh,
+        y2 = jax.shard_map(lambda xl, wl: ring_rs_matmul(xl, wl, "m"), mesh=mesh,
                        in_specs=(P("m", None), P("m", None)),
                        out_specs=P("m", None), check_vma=False)(x2, w2)
         np.testing.assert_allclose(np.asarray(y2), np.asarray(x2 @ w2),
@@ -130,6 +131,7 @@ def test_gpipe_odd_stage_count_matches_plain_forward():
         from repro.configs.registry import get_config
         from repro.models.model_api import build_model
         from repro.models import transformer as tfm
+        from repro.launch.mesh import make_mesh_shape
         from repro.runtime.pipeline import pipeline_forward
         from repro.sharding.plan import make_plan
 
@@ -141,7 +143,7 @@ def test_gpipe_odd_stage_count_matches_plain_forward():
         toks = jax.random.randint(jax.random.key(1), (M, mb, S), 0, cfg.vocab, jnp.int32)
 
         ref = tfm.forward(cfg, params, toks.reshape(M * mb, S), plan)
-        mesh = jax.make_mesh((3,), ("stage",))
+        mesh = make_mesh_shape((3,), ("stage",))
         got = pipeline_forward(cfg, params, toks, mesh)
         got = got.reshape(M * mb, S, -1)
         err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))))

@@ -13,6 +13,7 @@ def _args(**overrides):
         arch="granite-3-2b", reduced=True, steps=20, batch=4, seq=32,
         grad_accum=1, lr=1e-3, warmup=5, seed=0, workers=2, max_queue_size=4,
         ckpt_dir="", ckpt_every=50, log_every=100, mesh="none", metrics_out="",
+        layers=0,
         total_steps=20,  # pin the LR schedule across interrupted runs
     )
     base.update(overrides)
@@ -38,6 +39,20 @@ def test_resume_is_bit_identical_to_uninterrupted(tmp_path):
     part2 = run(_args(steps=20, ckpt_dir=str(tmp_path / "resume"), ckpt_every=100))
     assert part2["steps"] == 10  # resumed from 10
     np.testing.assert_allclose(part2["final_loss"], full["final_loss"], rtol=1e-5)
+
+
+def test_reduced_resnet_batch_follows_the_config():
+    """resnet_medium's dataset is ImageNet64 (64x64, 1000 classes); its
+    reduced config is 32x32 with 10 classes, and the batch must match it."""
+    r = run(_args(arch="resnet_medium", steps=2, batch=2))
+    assert len(r["losses"]) == 2 and np.all(np.isfinite(r["losses"])), r
+
+
+def test_layers_cuts_depth_and_is_recorded():
+    r = run(_args(steps=2, layers=1))
+    assert r["layers"] == 1 and np.isfinite(r["final_loss"]), r
+    with pytest.raises(ValueError, match="resnet"):
+        run(_args(arch="resnet_small", steps=1, layers=1))
 
 
 def test_grad_accum_matches_full_batch():

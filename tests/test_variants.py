@@ -31,6 +31,7 @@ def test_all_variants_match_baseline_loss():
         from repro.optim import adamw
         from repro.runtime import train_step as ts
         from repro.data import synthetic
+        from repro.launch.mesh import make_mesh_shape
 
         cfg = get_config("granite-3-2b").reduced()
         suite = ShapeSuite("t", 32, 8, "train")
@@ -38,7 +39,7 @@ def test_all_variants_match_baseline_loss():
         opt = adamw.AdamWConfig(warmup_steps=1, total_steps=10)
         batch = {k: jnp.asarray(v) for k, v in
                  synthetic.batch_for(cfg, suite, seed=0).items()}
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh_shape((2, 4), ("data", "model"))
         losses = {}
         for variant in ("baseline", "sp", "zero"):
             jitted, st_sh, b_sh, plan = ts.jit_train_step(
@@ -65,10 +66,11 @@ def test_serve_variant_decode_matches_baseline():
         from repro.runtime import serve_step as serve
         from repro.sharding.plan import make_plan
         from repro.runtime.serve_step import pad_cache
+        from repro.launch.mesh import make_mesh_shape
 
         cfg = get_config("granite-3-2b").reduced()
         model = build_model(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh_shape((2, 4), ("data", "model"))
         suite = ShapeSuite("d", 32, 8, "decode")
         params = model.init(jax.random.key(0))
         plan0 = make_plan(cfg, None)
