@@ -134,5 +134,6 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(kv_len_arr, qr, k_cache, v_cache)
     return out.reshape(B, H, D)

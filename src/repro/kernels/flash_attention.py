@@ -187,6 +187,7 @@ def flash_attention_fwd(
             pltpu.VMEM((rows, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -355,6 +356,7 @@ def flash_attention_bwd(
         ],
         compiler_params=_BWD_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, o, do, lse)
 
     # dq: grid (B, KVH, nq, nk) — q tile outer, kv tile inner
@@ -370,6 +372,7 @@ def flash_attention_bwd(
         scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)],
         compiler_params=_BWD_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, o, do, lse)[0]
 
     return dq, dkv[0], dkv[1]

@@ -149,5 +149,6 @@ def wkv6_scan(
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(r, k, v, logw, u.reshape(H, 1, K), state0)
     return out.transpose(0, 2, 1, 3), state

@@ -3,17 +3,18 @@
 The production entry point: build the model from ``--arch``, shard it over
 the chosen mesh, stream deterministic synthetic data through the host
 pipeline, checkpoint every ``--ckpt-every`` steps (async, atomic), resume
-automatically from the latest valid checkpoint, and log step time / loss /
-input-wait. On a CPU host use ``--reduced`` for a runnable config; on a
-TPU the same flags drive the published widths, with ``--layers`` cutting
-depth to fit one chip.
+automatically from the latest valid checkpoint, and log the loss and the
+host time of each phase of the loop step (``runtime/host_loop.py``). On a
+CPU host use ``--reduced`` for a runnable config; on a TPU the same flags
+drive the published widths, with ``--layers`` cutting depth to fit one chip.
 
   PYTHONPATH=src python -m repro.launch.train --arch granite-3-2b --reduced \
       --steps 50 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
 
 The step is compiled ahead of the loop, so ``compile_s`` in the result is
-set-up time and ``mean_step_ms`` (steps after the first three, each timed up
-to a device sync) is steady state.
+set-up time and ``mean_step_ms`` (steps after the first three, each timed
+from the compiled call to its device sync) is steady state. ``loop`` holds
+the per-phase counters of ``HostLoop.stats()``.
 """
 from __future__ import annotations
 
@@ -37,6 +38,7 @@ from repro.models.model_api import build_model
 from repro.optim import adamw
 from repro.launch.mesh import make_mesh_shape
 from repro.runtime import train_step as ts
+from repro.runtime.host_loop import HostLoop, phase_ms
 from repro.sharding.plan import make_plan
 
 # fixed, inside the checkout: the cache key includes the directory, so a
@@ -145,25 +147,26 @@ def run(args) -> dict:
         start_step=start_step,
     ).start()
 
+    loop = HostLoop(compiled, pipeline, job=args.arch, sharding=b_sh,
+                    start_step=start_step)
     losses = []
-    step_times = []
+    logged = warm = loop.stats()  # warm: after the first three steps
     t_train0 = time.perf_counter()
     try:
         for step in range(start_step, args.steps):
-            batch = jax.device_put(pipeline.get(), b_sh)
-            t0 = time.perf_counter()
-            state, metrics = jax.block_until_ready(compiled(state, batch))
-            step_times.append(time.perf_counter() - t0)
-            loss = float(metrics["loss"])
+            state, fetched = loop.step(state)
+            loss = fetched["loss"]
             losses.append(loss)
+            if len(losses) == 3:
+                warm = loop.stats()
             if not np.isfinite(loss):
                 raise FloatingPointError(f"non-finite loss {loss} at step {step}")
             if (step + 1) % args.log_every == 0:
-                print(
-                    f"[train] step {step + 1}/{args.steps} loss={loss:.4f} "
-                    f"step_time={np.mean(step_times[-args.log_every:]) * 1e3:.1f}ms",
-                    flush=True,
-                )
+                now = loop.stats()
+                phases = " ".join(f"{p}={ms:.2f}ms" for p, ms in phase_ms(logged, now).items())
+                logged = now
+                print(f"[train] step {step + 1}/{args.steps} loss={loss:.4f} {phases}",
+                      flush=True)
             if store and (step + 1) % args.ckpt_every == 0:
                 store.save(step + 1, state, extra={"loss": loss}, async_save=True)
     finally:
@@ -173,6 +176,7 @@ def run(args) -> dict:
         store.wait()
 
     wall = time.perf_counter() - t_train0
+    steady = phase_ms(warm, loop.stats())
     dev = jax.devices()[0]
     mem = dev.memory_stats() or {}
     result = {
@@ -188,13 +192,14 @@ def run(args) -> dict:
         # noisy to compare individually
         "head_mean_loss": float(np.mean(losses[:5])) if losses else None,
         "tail_mean_loss": float(np.mean(losses[-5:])) if losses else None,
-        "mean_step_ms": float(np.mean(step_times[3:]) * 1e3) if len(step_times) > 3 else None,
+        "mean_step_ms": steady["dispatch"] + steady["sync"] if len(losses) > 3 else None,
         "compile_s": compile_s,
         # Pallas kernels in the compiled step (0 on the XLA path)
         "tpu_custom_calls": hlo.count('custom_call_target="tpu_custom_call"'),
         "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
         "wall_s": wall,
         "pipeline": pipeline.stats(),
+        "loop": loop.stats(),
     }
     if args.metrics_out:
         Path(args.metrics_out).write_text(json.dumps(result, indent=2))

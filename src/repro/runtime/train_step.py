@@ -52,10 +52,14 @@ def build_train_step(
     def loss_fn(params, batch):
         return model.loss(params, batch, plan)
 
+    # named scopes prefix the ops' metadata, so a profile can split the step
+    # into forward/backward and optimizer
+    def loss_and_grads(params, batch):
+        with jax.named_scope("forward_backward"):
+            return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+
     def single(state, batch):
-        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state["params"], batch
-        )
+        (loss, metrics), grads = loss_and_grads(state["params"], batch)
         return loss, metrics, grads
 
     def accumulated(state, batch):
@@ -69,9 +73,7 @@ def build_train_step(
 
         def body(acc, mb):
             g_acc, loss_acc = acc
-            (loss, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                state["params"], mb
-            )
+            (loss, metrics), g = loss_and_grads(state["params"], mb)
             g_acc = jax.tree_util.tree_map(
                 lambda a, b: a + b.astype(jnp.float32), g_acc, g
             )
@@ -86,9 +88,10 @@ def build_train_step(
         loss, metrics, grads = (
             single(state, batch) if grad_accum == 1 else accumulated(state, batch)
         )
-        new_params, new_opt, opt_metrics = adamw.apply_updates(
-            state["params"], grads, state["opt"], opt_cfg
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw.apply_updates(
+                state["params"], grads, state["opt"], opt_cfg
+            )
         metrics = dict(metrics, loss=loss, **opt_metrics)
         return {"params": new_params, "opt": new_opt}, metrics
 

@@ -61,25 +61,29 @@ def test_flash_attention_fwd_and_grad_compile(one_chip, arch):
         return ops.flash_attention(q, k, v, mode="tpu")
 
     grad = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
-    for f in (fwd, grad):
+    # the kernels keep their names in the compiled program, for a profile
+    for f, names in ((fwd, ["flash_fwd"]), (grad, ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"])):
         hlo = jax.jit(f).lower(q, kv, kv).compile().as_text()
         assert 'custom_call_target="tpu_custom_call"' in hlo
+        assert all(n in hlo for n in names), names
 
 
 def test_decode_attention_compiles_at_llama3_8b_widths(one_chip):
     B, Smax, H, KVH, D = 8, 32_768, 32, 8, 128
     cache = _sds(one_chip, (B, Smax, KVH, D))
     f = jax.jit(lambda q, k, v, n: ops.decode_attention(q, k, v, kv_len=n, mode="tpu"))
-    f.lower(_sds(one_chip, (B, H, D)), cache, cache,
-            _sds(one_chip, (), jnp.int32)).compile()
+    hlo = f.lower(_sds(one_chip, (B, H, D)), cache, cache,
+                  _sds(one_chip, (), jnp.int32)).compile().as_text()
+    assert "%decode_attention" in hlo
 
 
 def test_wkv6_compiles_at_rwkv6_1_6b_widths(one_chip):
     B, T, H, K = 1, 4096, 32, 64
     seq = _sds(one_chip, (B, T, H, K), jnp.float32)
     f = jax.jit(lambda r, k, v, w, u, s: ops.wkv6(r, k, v, w, u, s, chunk=64, mode="tpu"))
-    f.lower(seq, seq, seq, seq, _sds(one_chip, (H, K), jnp.float32),
-            _sds(one_chip, (B, H, K, K), jnp.float32)).compile()
+    hlo = f.lower(seq, seq, seq, seq, _sds(one_chip, (H, K), jnp.float32),
+                  _sds(one_chip, (B, H, K, K), jnp.float32)).compile().as_text()
+    assert "%rwkv6_scan" in hlo
 
 
 def test_granite_train_step_compiles_with_pallas_attention(one_chip, monkeypatch):
@@ -106,7 +110,11 @@ def test_granite_train_step_compiles_with_pallas_attention(one_chip, monkeypatch
     step = jax.jit(ts.build_train_step(model, make_plan(cfg, None), opt),
                    donate_argnums=(0,))
     compiled = step.lower(put(state), put(batch)).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 3
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
+    # the step's ops carry its phases in their metadata
+    assert 'op_name="jit(train_step)/forward_backward/' in hlo
+    assert 'op_name="jit(train_step)/optimizer/' in hlo
     mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
