@@ -18,7 +18,11 @@ node posture rely on):
     *different* mesh — the elastic-repack path: a job killed on a 2g
     instance resumes on a 3g instance from the same files;
   * integrity: every shard file carries a crc32 in the manifest, checked on
-    restore.
+    restore;
+  * one format whatever the state's layout: a tree stored stacked
+    (``runtime.train_step.StackedState``, which has ``unstacked`` and
+    ``restack``) is written as its per-leaf tree and stacked again on
+    restore, so its files are those of the per-leaf state.
 """
 from __future__ import annotations
 
@@ -76,6 +80,8 @@ class CheckpointStore:
              async_save: bool = False) -> Path:
         """Save ``tree`` (pytree of jax/np arrays) at ``step``."""
         self.wait()  # join any in-flight async save (bounded memory)
+        if hasattr(tree, "unstacked"):
+            tree = tree.unstacked()
         # device->host copy NOW so the caller may donate/mutate afterwards
         host_leaves = []
         for key, leaf in _leaf_paths(tree):
@@ -180,6 +186,11 @@ class CheckpointStore:
         (may describe a different mesh than the one that saved — elastic
         resume). Returns (tree, extra).
         """
+        if hasattr(tree_like, "restack"):
+            per_leaf, extra = self.restore(
+                jax.eval_shape(lambda t: t.unstacked(), tree_like), step)
+            tree = tree_like.restack(per_leaf)
+            return (tree if shardings is None else jax.device_put(tree, shardings)), extra
         if step is None:
             step = self.latest_step()
             if step is None:

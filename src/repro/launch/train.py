@@ -14,7 +14,9 @@ drive the published widths, with ``--layers`` cutting depth to fit one chip.
 The step is compiled ahead of the loop, so ``compile_s`` in the result is
 set-up time and ``mean_step_ms`` (steps after the first three, each timed
 from the compiled call to its device sync) is steady state. ``loop`` holds
-the per-phase counters of ``HostLoop.stats()``.
+the per-phase counters of ``HostLoop.stats()``, ``state_arrays`` the
+arrays the step takes as its state, per leaf (``before``) and as stored
+(``after``: fewer when ``init_train_state`` stacks the leaves by shape).
 """
 from __future__ import annotations
 
@@ -122,6 +124,9 @@ def run(args) -> dict:
         jitted = jax.jit(step_fn, donate_argnums=(0,))
 
     state = ts.init_train_state(model, jax.random.key(args.seed), opt_cfg)
+    state_arrays = ts.state_arrays(state)
+    print(f"[train] state arrays {state_arrays['before']} -> {state_arrays['after']}",
+          flush=True)
     start_step = 0
 
     store = None
@@ -194,6 +199,8 @@ def run(args) -> dict:
         "tail_mean_loss": float(np.mean(losses[-5:])) if losses else None,
         "mean_step_ms": steady["dispatch"] + steady["sync"] if len(losses) > 3 else None,
         "compile_s": compile_s,
+        # arrays the compiled step takes as its state, per leaf -> as stored
+        "state_arrays": state_arrays,
         # Pallas kernels in the compiled step (0 on the XLA path)
         "tpu_custom_calls": hlo.count('custom_call_target="tpu_custom_call"'),
         "peak_bytes_in_use": mem.get("peak_bytes_in_use"),

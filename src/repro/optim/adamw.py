@@ -83,7 +83,14 @@ def apply_updates(
     grads: Params,
     state: AdamWState,
     cfg: AdamWConfig,
+    *,
+    decay: Any = None,
 ) -> Tuple[Params, AdamWState, Dict[str, jax.Array]]:
+    """One AdamW step. ``decay`` gives each leaf's weight-decay flag, as a
+    pytree of bools like ``params``; by default ``_decay_mask`` reads it from
+    the leaf's path."""
+    if decay is None:
+        decay = jax.tree_util.tree_map_with_path(lambda path, _: _decay_mask(path), params)
     grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
     grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
     step = state.step + 1
@@ -98,12 +105,12 @@ def apply_updates(
         lambda v, g: cfg.b2 * v + (1 - cfg.b2) * jnp.square(g), state.v, grads
     )
 
-    def upd(path, p, m, v):
+    def upd(p, m, v, d):
         u = (m / b1c) / (jnp.sqrt(v / b2c) + cfg.eps)
-        if cfg.weight_decay and _decay_mask(path):
+        if cfg.weight_decay and d:
             u = u + cfg.weight_decay * p.astype(jnp.float32)
         return (p.astype(jnp.float32) - lr * u).astype(p.dtype)
 
-    new_params = jax.tree_util.tree_map_with_path(upd, params, new_m, new_v)
+    new_params = jax.tree_util.tree_map(upd, params, new_m, new_v, decay)
     metrics = {"grad_norm": gnorm, "lr": lr}
     return new_params, AdamWState(step, new_m, new_v), metrics
