@@ -9,8 +9,11 @@ the lower readings. For each ``--control`` seed, job 0 of the cell with
 the program's readings replaced, each put through the same comparison
 under the cell's limits: the reference with its products rounded to
 float8 (the control), and the reference fed half of each batch (the fault
-"half the batch left out"): the upper readings. One JSON line per reading
-on standard output, with ``correct`` and each job's numbers.
+"half the batch left out"): the upper readings; for the first of them also
+the reference at a learning rate of 0, whose weights never move (the fault
+"a step that returns its state unchanged"). The reference runs where the
+cell's own does: on one chip, or laid over the cell's chips. One JSON line
+per reading on standard output, with ``correct`` and each job's numbers.
 """
 from __future__ import annotations
 
@@ -35,7 +38,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     c = run.load_cell(args.workload)
     try:
-        run.chips(c["cell"]["chips"])
+        devices = run.chips(c["cell"]["chips"])[: c["cell"]["chips"]]
     except run.NoChip as e:
         print(f"calibrate: {e}", file=sys.stderr)
         return 2
@@ -50,10 +53,16 @@ def main(argv=None) -> int:
         print(json.dumps({"kind": "program", "seed": seed, "correct": r["correct"],
                           "checks": r["checks"], "jobs": r["job_gaps"],
                           "metrics": r["metrics"], "peak": r["device"]["memory_peak_bytes"]}), flush=True)
-    for seed in [int(s) for s in args.control.split(",") if s]:
-        ref = compare.reference(cfg, traffic, seed, 0)
-        for kind, kw in (("control_fp8", {"lowp": True}), ("fault_half_batch", {"rows": _half})):
-            reading = compare.reference(cfg, traffic, seed, 0, **kw)
+    place = compare.placement(traffic, devices)
+    still = dict(traffic, optimizer=dict(traffic["optimizer"], lr_peak=0.0, lr_min=0.0))
+    for i, seed in enumerate(int(s) for s in args.control.split(",") if s):
+        ref = compare.reference(cfg, traffic, seed, 0, place=place)
+        kinds = [("control_fp8", traffic, {"lowp": True}),
+                 ("fault_half_batch", traffic, {"rows": _half})]
+        if i == 0:
+            kinds.append(("fault_state_unchanged", still, {}))
+        for kind, t, kw in kinds:
+            reading = compare.reference(cfg, t, seed, 0, place=place, **kw)
             ok, checks, jobs = compare.check(cfg, traffic, seed, [reading], limits, refs=[ref])
             print(json.dumps({"kind": kind, "seed": seed, "correct": ok, "checks": checks,
                               "jobs": jobs}), flush=True)

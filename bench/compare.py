@@ -54,11 +54,19 @@ def out_leaf(cfg_doc: dict) -> str:
     return importlib.import_module(f"refs.{cfg_doc['family']}").OUT_LEAF
 
 
+def placement(traffic: dict, devices):
+    """Where the reference runs: on one device as it stands (None), or laid
+    over the cell's devices (``common.spread``) where the traffic names a
+    mesh, as its program is."""
+    return common.spread(devices) if "mesh" in traffic else None
+
+
 def reference(cfg_doc: dict, traffic: dict, seed: int, job: int, *, lowp=False,
-              rows=None) -> dict:
-    """Readings of the plain reference for one job. For the control and the
-    planted faults of calibrate.py: ``lowp`` rounds its products to float8,
-    ``rows`` maps the three batches to what the reference is fed."""
+              rows=None, place=None) -> dict:
+    """Readings of the plain reference for one job, laid out by ``place``.
+    For the control and the planted faults of calibrate.py: ``lowp`` rounds
+    its products to float8, ``rows`` maps the three batches to what the
+    reference is fed."""
     init, loss = _fns(cfg_doc["family"], json.dumps(cfg_doc, sort_keys=True), lowp)
     ds = gen.data_seed(seed, job)
     batches = [{k: jnp.asarray(v) for k, v in gen.batch(cfg_doc, traffic, ds, s).items()}
@@ -66,7 +74,7 @@ def reference(cfg_doc: dict, traffic: dict, seed: int, job: int, *, lowp=False,
     if rows is not None:
         batches = rows(batches)
     return common.three_steps(init, loss, gen.job_key(seed, job), batches,
-                              traffic["optimizer"], out_leaf(cfg_doc))
+                              traffic["optimizer"], out_leaf(cfg_doc), place)
 
 
 def _leaf_gaps(prog: dict, ref: dict, keep) -> dict:
@@ -92,10 +100,11 @@ def gaps(prog: dict, ref: dict) -> dict:
             "out_grad_gap": out, "grad_leaf": grad_leaf, "change_leaf": change_leaf}
 
 
-def check(cfg_doc, traffic, seed, readings, limits, refs=None) -> tuple[bool, dict, list]:
+def check(cfg_doc, traffic, seed, readings, limits, refs=None,
+          place=None) -> tuple[bool, dict, list]:
     """Worst number over the cell's jobs, each beside its limit, and the
     numbers of each job. ``refs`` are the reference's readings where the
-    caller has them already."""
+    caller has them already; else it runs, laid out by ``place``."""
     numbers = [n for n in NUMBERS if n in limits]
     worst = {n: 0.0 for n in numbers}
     per_job = []
@@ -103,7 +112,8 @@ def check(cfg_doc, traffic, seed, readings, limits, refs=None) -> tuple[bool, di
         if not all(np.isfinite(prog["losses"])):
             worst = {n: float("inf") for n in numbers}
             break
-        ref = refs[job] if refs is not None else reference(cfg_doc, traffic, seed, job)
+        ref = (refs[job] if refs is not None
+               else reference(cfg_doc, traffic, seed, job, place=place))
         g = gaps(prog, ref)
         per_job.append(g)
         worst = {n: max(worst[n], g[n]) for n in numbers}

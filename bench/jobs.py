@@ -9,6 +9,9 @@ instead of a number of steps. Each job's loop runs on a thread of its own,
 from its first step to the end of the window, so the three steps the
 comparison reads interleave on the chip as the timed ones do. Host spans
 around each call are written into the profiler's trace when one is recording.
+Where the traffic names a ``mesh``, the step is the program's sharded one, as
+``repro.launch.train --mesh host`` builds it, and each job's state and batches
+are laid out by its shardings over the mesh's chips.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ from jax.profiler import TraceAnnotation
 from repro.configs.base import ShapeSuite
 from repro.configs.registry import get_config
 from repro.data.pipeline import HostPipeline
+from repro.launch.mesh import make_mesh_shape
 from repro.models.model_api import build_model
 from repro.optim import adamw
 from repro.runtime import train_step as ts
@@ -80,7 +84,8 @@ class Job:
 
 
 class Cell:
-    """Model, compiled step and jobs of one (configuration, traffic) cell."""
+    """Model, compiled step and jobs of one (configuration, traffic) cell:
+    on one chip, or where the traffic names a ``mesh``, sharded over it."""
 
     def __init__(self, cfg_doc: dict, traffic: dict, seed: int, out_leaf: str):
         self.cfg_doc, self.traffic, self.out_leaf = cfg_doc, traffic, out_leaf
@@ -89,10 +94,22 @@ class Cell:
         self.opt = opt_config(traffic)
         suite = ShapeSuite(traffic["name"], traffic.get("seq_len", 0),
                            traffic["batch"], "train")
-        step_fn = ts.build_train_step(self.model, make_plan(self.cfg, None), self.opt)
-        jitted = jax.jit(step_fn, donate_argnums=(0,))
-        # weights and optimizer state on the device, from the seed, in one call
-        init = jax.jit(lambda k: ts.init_train_state(self.model, k, self.opt))
+        make_state = lambda k: ts.init_train_state(self.model, k, self.opt)  # noqa: E731
+        mesh = traffic.get("mesh")
+        if mesh is None:
+            step_fn = ts.build_train_step(self.model, make_plan(self.cfg, None), self.opt)
+            jitted = jax.jit(step_fn, donate_argnums=(0,))
+            # weights and optimizer state on the device, from the seed, in one call
+            init = jax.jit(make_state)
+            self.devices, self.batch_sharding, params_sh = jax.devices()[:1], None, None
+        else:
+            # the launcher's --mesh host path: the program's sharded step, its
+            # state made shard by shard, so that no chip holds the whole state
+            mesh = make_mesh_shape(mesh["shape"], mesh["axes"])
+            jitted, state_sh, self.batch_sharding, _ = ts.jit_train_step(
+                self.model, mesh, suite, self.opt)
+            init = jax.jit(make_state, out_shardings=state_sh)
+            self.devices, params_sh = list(mesh.devices.flat), state_sh["params"]
         self.jobs = []
         for j in range(traffic["jobs"]):
             key = gen.job_key(seed, j)
@@ -105,10 +122,13 @@ class Cell:
                                      self.model.input_specs(suite)).compile()
         self.names = leaf_names(self.jobs[0].state["params"])
         self._norms = jax.jit(_leaf_norms)
+
+        def fresh(k):  # the initial weights again, laid out as the state's
+            p0 = self.model.init(k)
+            return p0 if params_sh is None else jax.lax.with_sharding_constraint(p0, params_sh)
+
         self._change = jax.jit(lambda p, k: _leaf_norms(jax.tree_util.tree_map(
-            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-            p, self.model.init(k))))
-        self.devices = jax.devices()[:1]
+            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, fresh(k))))
         self._threads: List[threading.Thread] = []
 
     def step(self, job: Job) -> float:
@@ -119,7 +139,7 @@ class Cell:
         with TraceAnnotation("pipeline.get"):
             batch = job.pipeline.get()
         with TraceAnnotation("device_put"):
-            batch = jax.device_put(batch)
+            batch = jax.device_put(batch, self.batch_sharding)
         with TraceAnnotation("step"):
             job.state, metrics = jax.block_until_ready(self.compiled(job.state, batch))
         with TraceAnnotation("loss"):

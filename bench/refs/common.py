@@ -14,6 +14,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0  # largest finite float8_e4m3fn
@@ -87,12 +88,47 @@ def _adamw_leaf(p, g, m, v, lr, b1c, b2c, scale, *, hp, decay):
     return (p.astype(jnp.float32) - lr * u).astype(p.dtype), m, v
 
 
-@functools.partial(jax.jit, static_argnames=("loss",))
-def _loss_and_grads(params, batch, *, loss):
+def _value_and_grads(params, batch, *, loss):
     """Loss and float32 gradients: taken at float32 copies of the weights,
     so no gradient is rounded to the weights' storage type."""
     up = jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params)
     return jax.value_and_grad(lambda p: loss(p, batch))(up)
+
+
+_loss_and_grads = jax.jit(_value_and_grads, static_argnames=("loss",))
+
+
+def spread(devices):
+    """The placement of the reference over several devices: each array is
+    laid along its largest dimension that their number divides (the first
+    of equal ones), and copied whole to each where none does. The
+    benchmark's own rule: it takes nothing from the program's sharding."""
+    mesh = Mesh(np.asarray(devices), ("spread",))
+    n = len(devices)
+
+    def place(shape) -> NamedSharding:
+        dims = sorted((d for d in range(len(shape)) if shape[d] % n == 0),
+                      key=lambda d: -shape[d])
+        spec = [None] * len(shape)
+        if dims:
+            spec[dims[0]] = "spread"
+        return NamedSharding(mesh, P(*spec))
+
+    return place
+
+
+def laid(place, tree):
+    """The sharding of each array of ``tree`` (arrays or shapes) under ``place``."""
+    return jax.tree_util.tree_map(lambda x: place(x.shape), tree)
+
+
+def loss_and_grads(place, params):
+    """``_loss_and_grads``; under a placement, with the gradients laid out
+    as ``params``."""
+    if place is None:
+        return _loss_and_grads
+    return jax.jit(_value_and_grads, static_argnames=("loss",),
+                   out_shardings=(None, laid(place, params)))
 
 
 @jax.jit
@@ -101,29 +137,41 @@ def _change(p, p0):
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, p0))
 
 
-def three_steps(init, loss, key, batches, opt: dict, out_leaf: str) -> dict:
+def three_steps(init, loss, key, batches, opt: dict, out_leaf: str, place=None) -> dict:
     """Three AdamW steps of ``loss`` from ``init(key)`` over ``batches``:
     each loss, each global gradient norm before clipping, the norms of the
     first gradient after clipping by the global norm, the whole first
     gradient of the output layer ``out_leaf`` before clipping, and each
     leaf's change after the three steps.
 
-    The moments stay in host memory and each leaf is updated on its own, so
-    that the device holds no more than the weights, one float32 copy of them
-    and their gradients at once.
+    On one device the moments stay in host memory and each leaf is updated
+    on its own, so that the device holds no more than the weights, one
+    float32 copy of them and their gradients at once. Under ``place``
+    (``spread``) the weights, their gradients and the moments are laid over
+    the devices, the moments kept there; the arithmetic is the same.
     """
-    params = jax.jit(init)(key)
+    shapes = jax.eval_shape(init, key)
+    if place is None:
+        make = jax.jit(init)
+        zeros = lambda x: np.zeros(x.shape, np.float32)  # noqa: E731
+        fetch = np.asarray
+    else:
+        make = jax.jit(init, out_shardings=laid(place, shapes))
+        zeros = lambda x: jnp.zeros(x.shape, jnp.float32, device=place(x.shape))  # noqa: E731
+        fetch = lambda x: x  # noqa: E731
+    grads_of = loss_and_grads(place, shapes)
+    params = make(key)
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     names = [jax.tree_util.keystr(p) for p, _ in leaves]
     decay = [_decayed(p) for p, _ in leaves]
     params = [x for _, x in leaves]
-    m = [np.zeros(x.shape, np.float32) for x in params]
-    v = [np.zeros(x.shape, np.float32) for x in params]
+    m = [zeros(x) for x in params]
+    v = [zeros(x) for x in params]
     b1, b2 = opt.get("b1", 0.9), opt.get("b2", 0.95)
     hp = (b1, b2, opt.get("eps", 1e-8), opt.get("weight_decay", 0.1))
     losses, gnorms, grad, out = [], [], None, None
     for step, batch in enumerate(batches, start=1):
-        value, grads = _loss_and_grads(tree.unflatten(params), batch, loss=loss)
+        value, grads = grads_of(tree.unflatten(params), batch, loss=loss)
         grads = jax.tree_util.tree_leaves(grads)
         gn = np.asarray(_norms(grads), np.float64)
         scale = min(1.0, opt.get("clip_norm", 1.0) / max(float(np.sqrt(np.sum(gn**2))), 1e-9))
@@ -136,9 +184,9 @@ def three_steps(init, loss, key, batches, opt: dict, out_leaf: str) -> dict:
             params[i], mi, vi = _adamw_leaf(
                 params[i], g, m[i], v[i], lr_at(opt, step), 1 - b1**step,
                 1 - b2**step, scale, hp=hp, decay=decay[i])
-            m[i], v[i] = np.asarray(mi), np.asarray(vi)
+            m[i], v[i] = fetch(mi), fetch(vi)
         del grads
     del m, v
-    change = np.asarray(_change(tree.unflatten(params), jax.jit(init)(key)))
+    change = np.asarray(_change(tree.unflatten(params), make(key)))
     return {"losses": losses, "gnorms": gnorms, "grad": dict(zip(names, grad.tolist())),
             "out_grad": out, "change": dict(zip(names, change.tolist()))}

@@ -88,7 +88,9 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, *, t0: float = T0)
 
     cfg, traffic = c["cfg"], c["traffic"]
     flops = importlib.import_module(f"flops.{cfg['family']}")
+    t_cell = time.perf_counter()
     cell = jobs.Cell(cfg, traffic, seed, compare.out_leaf(cfg))
+    t_built = time.perf_counter()
     readings = cell.checked_steps()
     setup_s = time.perf_counter() - t0
 
@@ -125,6 +127,8 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, *, t0: float = T0)
         run["trace"] = {
             "window_s": tr.window[1] - tr.window[0],
             "busy_s": statistics.fmean(tr.busy_s(d) for d in names),
+            # (collective, exposed collective) seconds, averaged over the chips
+            "collective_s": [statistics.fmean(x) for x in zip(*map(tr.collective_s, names))],
             "kernels": {
                 k: {"seconds": statistics.fmean(tr.op_seconds(d, pat) for d in names),
                     "least_s": steps / run["chips"] * sum(
@@ -134,7 +138,12 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, *, t0: float = T0)
             "breakdown": {"device_ops": tr.top_ops(), "idle_gaps": tr.gaps_by_host_span()},
         }
 
-    correct, checks, job_gaps = compare.check(cfg, traffic, seed, readings, c["limits"])
+    t_ref = time.perf_counter()
+    correct, checks, job_gaps = compare.check(cfg, traffic, seed, readings, c["limits"],
+                                              place=compare.placement(traffic, devices))
+    print(f"bench: set-up {setup_s:.1f} s (imports {t_cell - t0:.1f}, step and state "
+          f"{t_built - t_cell:.1f}, checked steps {t0 + setup_s - t_built:.1f}), window "
+          f"{end - start:.1f} s, reference {time.perf_counter() - t_ref:.1f} s", file=sys.stderr)
     correct = correct and failed == 0
     metrics = {}
     for m in c["per_layer" if trace else "end_to_end"]:

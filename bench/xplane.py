@@ -20,6 +20,31 @@ WINDOW = "bench_window"
 # host spans of the benchmark's loop (jobs.Cell.step), to name idle gaps by
 HOST_SPANS = ("pipeline.get", "device_put", "step", "loss")
 DEVICE_LINE = "XLA Ops"
+# XLA's collectives. An op is one where its own name or opcode holds one of
+# these, or the TPU compiler's async-collective-start / -done, or where it
+# calls a computation named for one (the fusion %all-reduce-scatter.N): the
+# collectives, their async halves and the fusions that are a collective. Not
+# one: an op that only takes a collective's result as an operand, and an
+# async_collective_fusion, which is a matmul that runs the gather of its next
+# operand inside it, hidden behind its own compute (its time is compute).
+# An op's text is "%name = type opcode(operands), attributes".
+COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce", "collective-permute", "all-to-all")
+_NAMED = re.compile("|".join(COLLECTIVES))
+_ASYNC = re.compile("async-collective-(start|done)")
+# the opcode: the first lower-case word before a parenthesis (a type's layout
+# letters are upper case)
+_OPCODE = re.compile(r"(?<![\w.%-])([a-z][a-z0-9-]*)\(")
+_CALLS = re.compile(r"calls=%([\w.-]+)")
+HOLDERS = ("while", "conditional", "call")  # ops that span the ops of a body
+
+
+def is_collective(op: str) -> bool:
+    name = op.partition(" = ")[0]
+    calls = _CALLS.search(op.partition(" = ")[2])
+    return (_NAMED.search(name) is not None or _ASYNC.search(name) is not None
+            or _NAMED.match(opcode(op)) is not None
+            or (calls is not None and _NAMED.match(calls.group(1)) is not None))
+
 
 Interval = Tuple[float, float]  # seconds on the trace clock
 
@@ -72,6 +97,16 @@ class Trace:
         """Summed durations of the ops whose name matches ``pattern``."""
         return sum(e - s for s, e, _ in self.ops(device, pattern))
 
+    def collective_s(self, device: str) -> Tuple[float, float]:
+        """Time in which a collective op runs, and the part of it in which
+        no other op does (exposed). Loops, branches and calls are left out:
+        only the ops they hold say what runs."""
+        coll, other = [], []
+        for s, e, n in leaves(self.ops(device)):
+            (coll if is_collective(n) else other).append((s, e))
+        coll = union(coll)
+        return measure(coll), measure(subtract(coll, union(other)))
+
     def idle_gaps(self, device: str) -> List[Interval]:
         return subtract([self.window], union([(s, e) for s, e, _ in self.ops(device)]))
 
@@ -105,6 +140,17 @@ class Trace:
                         best, name = ov, span
                 total[name] += (ge - gs) / len(self.devices)
         return [[k, v] for k, v in sorted(total.items(), key=lambda kv: -kv[1])[:n]]
+
+
+def opcode(op: str) -> str:
+    m = _OPCODE.search(op.partition(" = ")[2])
+    return m.group(1) if m else ""
+
+
+def leaves(ops: List[tuple]) -> List[tuple]:
+    """The (start, end, name) ops that are not a loop, a branch or a call:
+    such an op spans the ops of its body, which the trace lists too."""
+    return [o for o in ops if opcode(o[2]) not in HOLDERS]
 
 
 def union(intervals: List[Interval]) -> List[Interval]:
