@@ -11,6 +11,9 @@ Phases (one process: a chip belongs to the process that first touches it):
    layers, seq 2048, batch 4. The compiled step must hold the Pallas flash
    kernels (``tpu_custom_call``), and the kernel is checked against
    ``kernels/ref.mha_reference`` at the granite attention shape.
+   Then ``granite-4-h-micro``, its first period (10 layers: Mamba-2 and one
+   attention layer without positions) at published widths, seq 2048,
+   batch 2; its step must hold the flash kernels too.
 3. Two ``resnet_small`` jobs interleaved from two threads on the one chip
    (the paper's naive sharing); each job's losses must equal its solo run.
 4. ``--chips 4``: phase 2's model on a 2x2 (data, model) mesh against the
@@ -43,6 +46,8 @@ from repro.launch.train import build_argparser, run, use_compile_cache  # noqa: 
 PHASE1 = ["--arch", "resnet_medium", "--batch", "32", "--steps", "8"]
 PHASE2 = ["--arch", "granite-3-2b", "--layers", "8", "--seq", "2048",
           "--batch", "4", "--steps", "8"]
+PHASE2_HYBRID = ["--arch", "granite-4-h-micro", "--layers", "10", "--seq", "2048",
+                 "--batch", "2", "--steps", "6"]
 PHASE3 = ["--arch", "resnet_small", "--batch", "32", "--steps", "6"]
 # granite's attention: 32 query heads over 8 KV heads, head_dim 64
 GRANITE_ATTN = dict(B=1, S=2048, H=32, KVH=8, D=64)
@@ -170,6 +175,9 @@ def main(argv=None) -> int:
         check(r["tpu_custom_calls"] >= 3,
               f"phase2: {r['tpu_custom_calls']} tpu_custom_call ops in the step")
         flash_kernel_vs_ref(**GRANITE_ATTN)
+        r = train("phase2 granite-4-h-micro", PHASE2_HYBRID)
+        check(r["tpu_custom_calls"] >= 3,
+              f"phase2: {r['tpu_custom_calls']} tpu_custom_call ops in the hybrid's step")
         interleaved(PHASE3)
 
     print(json.dumps({"ok": True, "device": {

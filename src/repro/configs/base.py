@@ -38,7 +38,7 @@ class ModelConfig:
     """One config drives every family; unused fields stay at their defaults."""
 
     name: str
-    family: str  # dense|moe|rwkv|hybrid|encdec|vlm|resnet
+    family: str  # dense|moe|rwkv|hybrid|granite_hybrid|encdec|vlm|resnet
     n_layers: int
     d_model: int
     n_heads: int
@@ -56,6 +56,17 @@ class ModelConfig:
     # hybrid (zamba2): a single *shared* attention block applied before every
     # ``attn_every``-th ssm layer.
     attn_every: int = 0
+    # hybrid (granite-4.0-h): the kind of each published layer, "mamba" or
+    # "attention"; a model of ``n_layers`` runs the first ``n_layers`` of them
+    layer_types: Tuple[str, ...] = ()
+    # read by the granite_hybrid family: the embedding is scaled by
+    # ``embedding_multiplier``, each mixer and MLP output by
+    # ``residual_multiplier``, attention scores by ``attention_multiplier``
+    # (None: 1/sqrt(head_dim)), the logits divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # enc-dec (whisper)
     enc_layers: int = 0
     n_frames: int = 1500  # stub audio frontend: precomputed frame embeddings
@@ -74,6 +85,7 @@ class ModelConfig:
     attn_block_k: int = 1024
     logit_softcap: float = 0.0
     label_smoothing: float = 0.0
+    norm_eps: float = 1e-6  # RMSNorm epsilon
 
     @property
     def resolved_head_dim(self) -> int:
@@ -120,6 +132,12 @@ class ModelConfig:
             small["ssm"] = dataclasses.replace(
                 self.ssm, state_dim=8, head_dim=8, chunk=8, lora_rank=8,
             )
+        if self.layer_types:
+            # the first layer of each of the first three runs of one kind,
+            # so that both kinds and the switches between them are present
+            runs = [k for i, k in enumerate(self.layer_types)
+                    if i == 0 or k != self.layer_types[i - 1]][:3]
+            small.update(layer_types=tuple(runs), n_layers=len(runs))
         if self.family == "resnet":
             small.update(
                 img_size=min(self.img_size, 32),

@@ -12,6 +12,7 @@ from repro.configs.base import (
 )
 from repro.configs.deepseek_moe_16b import CONFIG as _deepseek
 from repro.configs.granite_3_2b import CONFIG as _granite
+from repro.configs.granite_4_h_micro import CONFIG as _granite4h
 from repro.configs.llama3_8b import CONFIG as _llama3
 from repro.configs.llava_next_34b import CONFIG as _llava
 from repro.configs.olmoe_1b_7b import CONFIG as _olmoe
@@ -22,7 +23,7 @@ from repro.configs.stablelm_12b import CONFIG as _stablelm
 from repro.configs.whisper_base import CONFIG as _whisper
 from repro.configs.zamba2_7b import CONFIG as _zamba2
 
-# the 10 assigned architectures
+# the assigned architectures
 ASSIGNED: Dict[str, ModelConfig] = {
     "stablelm-12b": _stablelm,
     "qwen2-72b": _qwen2,
@@ -34,6 +35,7 @@ ASSIGNED: Dict[str, ModelConfig] = {
     "olmoe-1b-7b": _olmoe,
     "whisper-base": _whisper,
     "zamba2-7b": _zamba2,
+    "granite-4-h-micro": _granite4h,
 }
 
 # the paper's own workload trio (collocation study)
@@ -53,7 +55,7 @@ def get_config(name: str) -> ModelConfig:
 
 
 def dryrun_grid() -> List[Tuple[str, str, bool, str]]:
-    """The full 40-cell grid: (arch, shape, applicable, skip_reason)."""
+    """The full grid, every assigned arch x shape: (arch, shape, applicable, skip_reason)."""
     cells = []
     for arch, cfg in ASSIGNED.items():
         for suite in ALL_SHAPES:

@@ -27,6 +27,9 @@ from repro.sharding.plan import ShardingPlan
 
 Params = Dict[str, Any]
 
+# step sizes at initialisation (mamba_ssm's dt_min, dt_max, dt_init_floor)
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
+
 
 # ---------------------------------------------------------------------------
 # chunked SSD core
@@ -109,22 +112,30 @@ def _inner(cfg: ModelConfig) -> Tuple[int, int, int, int]:
 
 
 def init_mamba_block(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Input norm and Mamba-2 mixer. A and the step sizes are drawn as
+    ``mamba_ssm`` draws them: A ~ U[1, 16], and the dt bias is the inverse
+    softplus of dt, log-uniform in [1e-3, 1e-1], so that the slowest heads
+    carry their state across many chunks."""
     kg = nn.KeyGen(key)
     d = cfg.d_model
     d_inner, H, P, N = _inner(cfg)
     s = cfg.ssm
     conv_ch = d_inner + 2 * N  # x, B, C go through the short conv
+    w_in = nn.fan_in_init(kg(), (d, 2 * d_inner + 2 * N + H), jnp.bfloat16)  # [z, x, B, C, dt]
+    conv_w = nn.trunc_normal(kg(), (s.d_conv, conv_ch), 0.1, jnp.bfloat16)
+    A = jax.random.uniform(kg(), (H,), jnp.float32, 1.0, 16.0)
+    u = jax.random.uniform(kg(), (H,), jnp.float32)
+    dt = jnp.maximum(jnp.exp(u * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN)),
+                     DT_FLOOR)
     return {
         "norm": nn.rmsnorm_init(d),
-        # fused in-proj: [z, x, B, C, dt]
-        "w_in": nn.fan_in_init(kg(), (d, 2 * d_inner + 2 * N + H), jnp.bfloat16),
-        "conv_w": nn.trunc_normal(kg(), (s.d_conv, conv_ch), 0.1, jnp.bfloat16),
+        "w_in": w_in,
+        "conv_w": conv_w,
         "conv_b": jnp.zeros((conv_ch,), jnp.bfloat16),
-        "A_log": jnp.log(
-            jnp.linspace(1.0, 16.0, H, dtype=jnp.float32)
-        ),  # A = -exp(A_log)
+        "A_log": jnp.log(A),  # A = -exp(A_log)
         "D": jnp.ones((H,), jnp.float32),
-        "dt_bias": jnp.zeros((H,), jnp.float32),
+        # softplus(bias) = dt; kept under "bias" so that no optimizer decays it
+        "dt": {"bias": dt + jnp.log(-jnp.expm1(-dt))},
         "out_norm": nn.rmsnorm_init(d_inner),
         "w_out": nn.fan_in_init(
             kg(), (d_inner, d), jnp.bfloat16, scale=1.0 / (2 * cfg.n_layers) ** 0.5
@@ -155,6 +166,14 @@ def _causal_conv_seq(w, b, x, tail: Optional[jax.Array] = None):
     return jax.nn.silu(y.astype(jnp.float32)).astype(x.dtype), xp[:, -(K - 1) :, :]
 
 
+def _gated_out(cfg: ModelConfig, p: Params, y: jax.Array, z: jax.Array) -> jax.Array:
+    """Output projection of y gated before the norm, as Mamba-2's
+    RMSNormGated(norm_before_gate=False): rmsnorm(y * silu(z)) @ W_out."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    return nn.dense_apply({"w": p["w_out"]},
+                          nn.rmsnorm_apply(p["out_norm"], g, eps=cfg.norm_eps))
+
+
 def mamba_seq(
     cfg: ModelConfig,
     p: Params,
@@ -165,30 +184,26 @@ def mamba_seq(
 ):
     B, T, d = x.shape
     d_inner, H, P, N = _inner(cfg)
-    xn = nn.rmsnorm_apply(p["norm"], x)
+    xn = nn.rmsnorm_apply(p["norm"], x, eps=cfg.norm_eps)
     proj = nn.dense_apply({"w": p["w_in"]}, xn)
     z, xin, Bc, Cc, dt = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
     conv_out, new_tail = _causal_conv_seq(p["conv_w"], p["conv_b"], conv_in, conv_tail)
     xin, Bc, Cc = jnp.split(conv_out, [d_inner, d_inner + N], axis=-1)
-    dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, None, :])
+    dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt"]["bias"][None, None, :])
     A = -jnp.exp(p["A_log"])
     xh = plan.act(xin.reshape(B, T, H, P), "heads")
-    y, state = ssd_chunked(xh, dtv, A, Bc, Cc, state0, chunk=cfg.ssm.chunk)
+    with jax.named_scope("ssd_scan"):
+        y, state = ssd_chunked(xh, dtv, A, Bc, Cc, state0, chunk=cfg.ssm.chunk)
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(B, T, d_inner).astype(jnp.bfloat16)
-    y = nn.rmsnorm_apply(p["out_norm"], y) * jax.nn.silu(
-        z.astype(jnp.float32)
-    ).astype(jnp.bfloat16)
-    out = nn.dense_apply({"w": p["w_out"]}, y)
-    return out, state, new_tail
+    return _gated_out(cfg, p, y.reshape(B, T, d_inner), z), state, new_tail
 
 
 def mamba_step(cfg: ModelConfig, p: Params, x, state, conv_tail):
     """x: (B,d). conv_tail: (B, K-1, C)."""
     B, d = x.shape
     d_inner, H, P, N = _inner(cfg)
-    xn = nn.rmsnorm_apply(p["norm"], x)
+    xn = nn.rmsnorm_apply(p["norm"], x, eps=cfg.norm_eps)
     proj = nn.dense_apply({"w": p["w_in"]}, xn)
     z, xin, Bc, Cc, dt = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)  # (B,C)
@@ -197,15 +212,11 @@ def mamba_step(cfg: ModelConfig, p: Params, x, state, conv_tail):
     y = jnp.einsum("bkc,kc->bc", window, w.astype(window.dtype)) + p["conv_b"]
     y = jax.nn.silu(y.astype(jnp.float32)).astype(x.dtype)
     xin, Bc, Cc = jnp.split(y, [d_inner, d_inner + N], axis=-1)
-    dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, :])
+    dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt"]["bias"][None, :])
     A = -jnp.exp(p["A_log"])
     yh, state = ssd_step(xin.reshape(B, H, P), dtv, A, Bc, Cc, state)
     yh = yh + p["D"][None, :, None] * xin.reshape(B, H, P).astype(jnp.float32)
-    yh = yh.reshape(B, d_inner).astype(jnp.bfloat16)
-    yh = nn.rmsnorm_apply(p["out_norm"], yh) * jax.nn.silu(
-        z.astype(jnp.float32)
-    ).astype(jnp.bfloat16)
-    return nn.dense_apply({"w": p["w_out"]}, yh), state, window[:, 1:, :]
+    return _gated_out(cfg, p, yh.reshape(B, d_inner), z), state, window[:, 1:, :]
 
 
 # ---------------------------------------------------------------------------

@@ -150,6 +150,7 @@ def build_model(cfg: ModelConfig) -> Model:
     from repro.models import moe as _moe  # noqa: F401
     from repro.models import rwkv6 as _rwkv6  # noqa: F401
     from repro.models import mamba2 as _mamba2  # noqa: F401
+    from repro.models import granite_hybrid as _granite_hybrid  # noqa: F401
     from repro.models import encdec as _encdec  # noqa: F401
     from repro.models import resnet as _resnet  # noqa: F401
 

@@ -100,7 +100,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
 def _norm(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.norm == "rmsnorm":
-        return nn.rmsnorm_apply(p, x)
+        return nn.rmsnorm_apply(p, x, eps=cfg.norm_eps)
     return nn.layernorm_apply(p, x)
 
 

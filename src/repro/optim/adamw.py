@@ -74,7 +74,7 @@ def _decay_mask(path) -> bool:
     names = [str(e.key) for e in path if isinstance(e, jax.tree_util.DictKey)]
     leaf = names[-1] if names else ""
     no_decay = ("scale", "bias", "mu", "decay_base", "bonus_u", "b", "bq", "bk",
-                "bv", "bo", "b_up", "b_down", "dt_bias", "a_log", "d_skip")
+                "bv", "bo", "b_up", "b_down")
     return leaf not in no_decay
 
 

@@ -24,6 +24,7 @@ DECODE_ARCHS = [
     "rwkv6-1.6b",       # attention-free recurrence
     "olmoe-1b-7b",      # MoE
     "zamba2-7b",        # mamba2 hybrid
+    "granite-4-h-micro",  # mamba2 + NoPE GQA layers: SSM state and KV caches
 ]
 
 
@@ -46,6 +47,10 @@ def _fwd_logits(cfg, model, params, tokens, plan):
         from repro.models import mamba2
 
         return mamba2.forward(cfg, params, tokens, plan)
+    if fam == "granite_hybrid":
+        from repro.models import granite_hybrid
+
+        return granite_hybrid.forward(cfg, params, tokens, plan)
     raise ValueError(fam)
 
 
