@@ -17,7 +17,9 @@ softmax recurrence is re-tiled for the MXU and VMEM:
     lane-replicated in HBM too, because Mosaic cannot turn a lane-dense
     (Bq, G) tile back into the (rows, 1) column the backward needs;
   * causal q-tiles skip fully-masked KV tiles via ``pl.when`` on the grid
-    index (≈2x fewer MXU ops at long seq).
+    index (≈2x fewer MXU ops at long seq), and only the tiles that straddle
+    the diagonal build the mask: a tile wholly below it runs a second body
+    with no iota, compare or select (``tile_classes`` counts each kind).
 
 Backward follows the two-kernel FlashAttention-2 schedule: a dk/dv kernel
 with the q dim innermost, and a dq kernel with the KV dim innermost; both
@@ -39,6 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # stat tiles are lane-replicated to this width
+BLOCK = 512  # default q and KV block of every entry point
 # At block 512 with G=4 folded rows the backward kernels hold several
 # (2048, 512) f32 tiles at once, just over Mosaic's 16 MiB default scoped
 # VMEM; v5e has 128 MiB of VMEM per core.
@@ -50,6 +53,73 @@ def _row_positions(block_q: int, g: int, iq, q_offset: int):
     rows = block_q * g
     r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     return q_offset + iq * block_q + r // g  # (rows, 1)
+
+
+def _causal_mask(s, *, block_q, block_k, g, iq, ik, q_offset):
+    """Scores of one tile with every key after its row's query set to NEG_INF."""
+    kv_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(_row_positions(block_q, g, iq, q_offset) >= kv_pos, s, NEG_INF)
+
+
+def _blocks(sq: int, skv: int, block_q: int, block_k: int) -> Tuple[int, int]:
+    """Blocks as the kernels use them: clamped to the lengths, which they must
+    divide (no tile holds padding, so no tile masks beyond the diagonal)."""
+    block_q, block_k = min(block_q, sq), min(block_k, skv)
+    if sq % block_q or skv % block_k:
+        raise ValueError(f"seq ({sq},{skv}) must divide blocks ({block_q},{block_k})")
+    return block_q, block_k
+
+
+# Where a (q tile iq, KV tile ik) pair lies against the causal diagonal. Both
+# take grid indices, traced in a kernel or Python ints in ``tile_classes``.
+def _tile_live(iq, ik, *, causal, block_q, block_k, q_offset):
+    """Some query of the tile may see some key of it: the tile's first KV
+    position is at or before its last query position."""
+    return not causal or ik * block_k <= q_offset + (iq + 1) * block_q - 1
+
+
+def _tile_masked(iq, ik, *, causal, block_q, block_k, q_offset):
+    """The tile straddles the diagonal (or lies above it): its last KV
+    position passes its first query position. A live tile that is not
+    masked lies wholly below the diagonal, and its mask is all true."""
+    return causal and (ik + 1) * block_k - 1 > q_offset + iq * block_q
+
+
+def _geom(kw: dict) -> dict:
+    """The keywords that place a tile against the diagonal."""
+    return {k: kw[k] for k in ("causal", "block_q", "block_k", "q_offset")}
+
+
+def _run_live_tile(tile, iq, ik, **geom):
+    """Call ``tile(masked)`` on a live tile: ``masked=True`` only where it
+    straddles the diagonal. Tiles above the diagonal run nothing."""
+    if not geom["causal"]:
+        tile(False)
+        return
+    live = _tile_live(iq, ik, **geom)
+    masked = _tile_masked(iq, ik, **geom)
+    pl.when(live & masked)(lambda: tile(True))
+    pl.when(live & ~masked)(lambda: tile(False))
+
+
+def tile_classes(sq: int, skv: int, block_q: int, block_k: int, causal: bool,
+                 q_offset: int = 0) -> dict:
+    """Tiles of one (batch, KV head) grid by how the kernels treat them:
+    ``skipped`` (above the diagonal), ``masked`` (straddling it) and
+    ``unmasked`` (live, mask all true). The blocks are clamped as the kernels
+    clamp them."""
+    block_q, block_k = _blocks(sq, skv, block_q, block_k)
+    geom = dict(causal=causal, block_q=block_q, block_k=block_k, q_offset=q_offset)
+    counts = {"skipped": 0, "masked": 0, "unmasked": 0}
+    for iq in range(sq // block_q):
+        for ik in range(skv // block_k):
+            if not _tile_live(iq, ik, **geom):
+                counts["skipped"] += 1
+            elif _tile_masked(iq, ik, **geom):
+                counts["masked"] += 1
+            else:
+                counts["unmasked"] += 1
+    return counts
 
 
 # ---------------------------------------------------------------------------
@@ -72,7 +142,6 @@ def _fwd_kernel(
     block_q: int,
     block_k: int,
     g: int,
-    kv_valid: int,
     q_offset: int,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
@@ -85,12 +154,7 @@ def _fwd_kernel(
         m[...] = jnp.full_like(m, NEG_INF)
         l[...] = jnp.zeros_like(l)
 
-    # causal: skip KV tiles strictly above the diagonal of this q tile
-    q_hi = q_offset + (iq + 1) * block_q - 1  # last q position in tile
-    live = (ik * block_k <= q_hi) if causal else (ik >= 0)
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         q = q_ref[0, 0].reshape(rows, q_ref.shape[-1]).astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)  # (Bk, D)
         v = v_ref[0, 0].astype(jnp.float32)  # (Bk, D)
@@ -98,14 +162,9 @@ def _fwd_kernel(
             q * scale, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (rows, Bk)
-
-        kv_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_k), 1
-        )
-        mask = kv_pos < kv_valid
-        if causal:
-            mask &= _row_positions(block_q, g, iq, q_offset) >= kv_pos
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            s = _causal_mask(s, block_q=block_q, block_k=block_k, g=g, iq=iq,
+                             ik=ik, q_offset=q_offset)
 
         m_prev = m[:, :1]  # (rows, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -118,6 +177,9 @@ def _fwd_kernel(
         )
         m[...] = jnp.broadcast_to(m_new, m.shape)
         l[...] = jnp.broadcast_to(l_new, l.shape)
+
+    _run_live_tile(_compute, iq, ik, causal=causal, block_q=block_q,
+                   block_k=block_k, q_offset=q_offset)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -134,8 +196,8 @@ def flash_attention_fwd(
     *,
     causal: bool,
     scale: float,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = BLOCK,
+    block_k: int = BLOCK,
     q_offset: int = 0,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -146,12 +208,8 @@ def flash_attention_fwd(
     """
     B, KVH, Sq, G, D = q.shape
     Skv = k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Skv)
-    nq = -(-Sq // block_q)
-    nk = -(-Skv // block_k)
-    if Sq % block_q or Skv % block_k:
-        raise ValueError(f"seq ({Sq},{Skv}) must divide blocks ({block_q},{block_k})")
+    block_q, block_k = _blocks(Sq, Skv, block_q, block_k)
+    nq, nk = Sq // block_q, Skv // block_k
 
     grid = (B, KVH, nq, nk)
     kernel = functools.partial(
@@ -161,7 +219,6 @@ def flash_attention_fwd(
         block_q=block_q,
         block_k=block_k,
         g=G,
-        kv_valid=Skv,
         q_offset=q_offset,
     )
     rows = block_q * G
@@ -197,7 +254,7 @@ def flash_attention_fwd(
 
 
 def _bwd_tile(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *, scale, causal,
-              block_q, block_k, g, kv_valid, q_offset, iq, ik):
+              block_q, block_k, g, q_offset, iq, ik, masked):
     """Recompute one (q tile, kv tile) pair: returns (q, k, do, p, ds)."""
     rows = block_q * g
     D = q_ref.shape[-1]
@@ -213,13 +270,9 @@ def _bwd_tile(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *, scale, causal,
         q * scale, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    kv_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, block_k), 1
-    )
-    mask = kv_pos < kv_valid
-    if causal:
-        mask &= _row_positions(block_q, g, iq, q_offset) >= kv_pos
-    s = jnp.where(mask, s, NEG_INF)
+    if masked:
+        s = _causal_mask(s, block_q=block_q, block_k=block_k, g=g, iq=iq, ik=ik,
+                         q_offset=q_offset)
     p = jnp.exp(s - lse)  # (rows, Bk) — true softmax probs
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -248,13 +301,10 @@ def _bwd_dkv_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_hi = common["q_offset"] + (iq + 1) * common["block_q"] - 1
-    live = (ik * common["block_k"] <= q_hi) if common["causal"] else (ik >= 0)
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         q, _, do, p, ds = _bwd_tile(
-            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, iq=iq, ik=ik, **common
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, iq=iq, ik=ik,
+            masked=masked, **common
         )
         # dv += p^T @ do ; dk += ds^T @ q * scale
         dv_acc[...] += jax.lax.dot_general(
@@ -263,6 +313,8 @@ def _bwd_dkv_kernel(
         dk_acc[...] += common["scale"] * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
+
+    _run_live_tile(_compute, iq, ik, **_geom(common))
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -288,17 +340,16 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_hi = common["q_offset"] + (iq + 1) * common["block_q"] - 1
-    live = (ik * common["block_k"] <= q_hi) if common["causal"] else (ik >= 0)
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         _, k, _, _, ds = _bwd_tile(
-            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, iq=iq, ik=ik, **common
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, iq=iq, ik=ik,
+            masked=masked, **common
         )
         dq_acc[...] += common["scale"] * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
+
+    _run_live_tile(_compute, iq, ik, **_geom(common))
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -310,21 +361,20 @@ def flash_attention_bwd(
     *,
     causal: bool,
     scale: float,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = BLOCK,
+    block_k: int = BLOCK,
     q_offset: int = 0,
     interpret: bool = False,
 ):
     """Returns (dq, dk, dv) with the layouts of (q, k, v)."""
     B, KVH, Sq, G, D = q.shape
     Skv = k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Skv)
+    block_q, block_k = _blocks(Sq, Skv, block_q, block_k)
     nq, nk = Sq // block_q, Skv // block_k
 
     common = dict(
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        g=G, kv_valid=Skv, q_offset=q_offset,
+        g=G, q_offset=q_offset,
     )
     rows = block_q * G
     # index maps take (b, h, outer, inner); q_of/kv_of pick the tile index

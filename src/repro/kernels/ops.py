@@ -92,8 +92,8 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = fa.BLOCK,
+    block_k: int = fa.BLOCK,
     mode: Optional[str] = None,
 ) -> jax.Array:
     """GQA flash attention with the model-API layout. Differentiable."""

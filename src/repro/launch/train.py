@@ -17,6 +17,9 @@ from the compiled call to its device sync) is steady state. ``loop`` holds
 the per-phase counters of ``HostLoop.stats()``, ``state_arrays`` the
 arrays the step takes as its state, per leaf (``before``) and as stored
 (``after``: fewer when ``init_train_state`` stacks the leaves by shape).
+Where the step takes the Pallas flash kernels, ``flash_tiles`` counts the
+tiles of one (batch row, KV head) grid that they skip, mask and run
+unmasked at ``--seq`` (``kernels/flash_attention.py:tile_classes``).
 """
 from __future__ import annotations
 
@@ -36,6 +39,7 @@ from repro.configs.registry import get_config
 from repro.checkpoint.store import CheckpointStore
 from repro.data import synthetic
 from repro.data.pipeline import HostPipeline
+from repro.kernels import flash_attention as fa
 from repro.models.model_api import build_model
 from repro.optim import adamw
 from repro.launch.mesh import make_mesh_shape
@@ -144,6 +148,11 @@ def run(args) -> dict:
     compiled = jitted.lower(state, model.input_specs(suite)).compile()
     compile_s = time.perf_counter() - t_compile0
     hlo = compiled.as_text()
+    flash_tiles = None
+    if "flash_fwd" in hlo:  # the step takes the Pallas flash kernels
+        flash_tiles = fa.tile_classes(args.seq, args.seq, fa.BLOCK, fa.BLOCK, causal=True)
+        print("[train] flash tiles " + " ".join(f"{k} {n}" for k, n in flash_tiles.items()),
+              flush=True)
 
     pipeline = HostPipeline(
         lambda step: synthetic.batch_for(cfg, suite, seed=args.seed, step=step),
@@ -203,6 +212,8 @@ def run(args) -> dict:
         "state_arrays": state_arrays,
         # Pallas kernels in the compiled step (0 on the XLA path)
         "tpu_custom_calls": hlo.count('custom_call_target="tpu_custom_call"'),
+        # per (batch row, KV head): flash tiles skipped / masked / unmasked
+        "flash_tiles": flash_tiles,
         "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
         "wall_s": wall,
         "pipeline": pipeline.stats(),

@@ -97,6 +97,117 @@ def test_flash_lse_is_true_logsumexp():
     )
 
 
+# Tile layouts against the causal diagonal: the kernels skip the tiles above
+# it, mask the tiles that straddle it and run the tiles below it unmasked.
+TILE_LAYOUTS = [
+    # (Sq, Skv, bq, bk, q_offset, causal)
+    (128, 128, 32, 32, 0, True),   # bq = bk
+    (128, 128, 16, 32, 0, True),   # bq < bk
+    (128, 128, 64, 16, 0, True),   # bq > bk
+    (64, 128, 16, 32, 64, True),   # queries after a prefix: q_offset > 0
+    (128, 128, 32, 64, 0, False),  # non-causal: no masked tiles
+]
+
+
+def _tile_inputs(Sq, Skv, B=2, H=8, KVH=2, D=32):
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Skv, KVH, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Skv, KVH, D), jnp.float32)
+    do = jax.random.normal(ks[3], (B, Sq, H, D), jnp.float32)
+    return q, k, v, do
+
+
+@pytest.mark.parametrize("layout", TILE_LAYOUTS)
+def test_flash_tile_layouts_fwd_and_lse(layout):
+    Sq, Skv, bq, bk, q_offset, causal = layout
+    q, k, v, _ = _tile_inputs(Sq, Skv)
+    B, _, H, D = q.shape
+    KVH = k.shape[2]
+    scale = D**-0.5
+    o, lse = fa.flash_attention_fwd(
+        ops._fold(q, KVH), ops._kv_fold(k), ops._kv_fold(v), causal=causal,
+        scale=scale, block_q=bq, block_k=bk, q_offset=q_offset, interpret=True,
+    )
+    want = ref.mha_reference(q, k, v, causal=causal, q_offset=q_offset)
+    np.testing.assert_allclose(ops._unfold(o), want, **_tol(jnp.float32))
+    scores = jnp.einsum("bqhgd,bkhd->bhqgk", q.reshape(B, Sq, KVH, H // KVH, D) * scale, k)
+    if causal:
+        mask = (q_offset + jnp.arange(Sq))[:, None] >= jnp.arange(Skv)[None, :]
+        scores = jnp.where(mask[None, None, :, None, :], scores, -1e30)
+    want_lse = jax.scipy.special.logsumexp(scores, axis=-1)  # (B, KVH, Sq, G)
+    np.testing.assert_array_equal(lse, jnp.broadcast_to(lse[..., :1], lse.shape))
+    np.testing.assert_allclose(
+        lse[..., 0].reshape(want_lse.shape), want_lse, atol=1e-4, rtol=1e-4
+    )
+
+
+@pytest.mark.parametrize("layout", TILE_LAYOUTS)
+def test_flash_tile_layouts_bwd(layout):
+    Sq, Skv, bq, bk, q_offset, causal = layout
+    q, k, v, do = _tile_inputs(Sq, Skv)
+    KVH, D = k.shape[2], q.shape[-1]
+    kw = dict(causal=causal, scale=D**-0.5, block_q=bq, block_k=bk,
+              q_offset=q_offset, interpret=True)
+    qf, kf, vf = ops._fold(q, KVH), ops._kv_fold(k), ops._kv_fold(v)
+    o, lse = fa.flash_attention_fwd(qf, kf, vf, **kw)
+    dq, dk, dv = fa.flash_attention_bwd(qf, kf, vf, o, lse, ops._fold(do, KVH), **kw)
+    _, vjp = jax.vjp(
+        lambda q, k, v: ref.mha_reference(q, k, v, causal=causal, q_offset=q_offset),
+        q, k, v,
+    )
+    want = vjp(do)
+    for name, a, b in zip(("dq", "dk", "dv"),
+                          (ops._unfold(dq), ops._kv_fold(dk), ops._kv_fold(dv)), want):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4, err_msg=name)
+
+
+def _brute_tile_classes(Sq, Skv, bq, bk, q_offset, causal):
+    """Classify each tile by the mask over its elements."""
+    counts = {"skipped": 0, "masked": 0, "unmasked": 0}
+    for iq in range(Sq // bq):
+        q_pos = q_offset + iq * bq + np.arange(bq)
+        for ik in range(Skv // bk):
+            seen = q_pos[:, None] >= ik * bk + np.arange(bk)[None, :]
+            if not causal:
+                seen[:] = True
+            kind = "unmasked" if seen.all() else "masked" if seen.any() else "skipped"
+            counts[kind] += 1
+    return counts
+
+
+@pytest.mark.parametrize("layout", TILE_LAYOUTS + [(4096, 4096, 512, 512, 0, True)])
+def test_tile_classes_match_brute_force(layout):
+    Sq, Skv, bq, bk, q_offset, causal = layout
+    got = fa.tile_classes(Sq, Skv, bq, bk, causal, q_offset)
+    assert got == _brute_tile_classes(*layout)
+    assert sum(got.values()) == (Sq // bq) * (Skv // bk)
+    if causal:  # every causal layout above holds all three kinds
+        assert min(got.values()) > 0, got
+    else:
+        assert got["masked"] == got["skipped"] == 0
+
+
+def test_tile_classes_at_granite_l12():
+    # seq 4096, the kernels' default blocks: the mask runs on 8 of 36 live tiles
+    assert fa.tile_classes(4096, 4096, 512, 512, True) == {
+        "skipped": 28, "masked": 8, "unmasked": 28}
+
+
+@pytest.mark.parametrize("entry", ["fwd", "bwd"])
+def test_flash_rejects_lengths_off_the_blocks(entry):
+    B, KVH, S, G, D = 1, 2, 48, 2, 16
+    q = jnp.zeros((B, KVH, S, G, D), jnp.float32)
+    kv = jnp.zeros((B, KVH, S, D), jnp.float32)
+    kw = dict(causal=True, scale=D**-0.5, block_q=32, block_k=32, interpret=True)
+    with pytest.raises(ValueError, match="must divide blocks"):
+        if entry == "fwd":
+            fa.flash_attention_fwd(q, kv, kv, **kw)
+        else:
+            lse = jnp.zeros((B, KVH, S * G, fa.LANES), jnp.float32)
+            fa.flash_attention_bwd(q, kv, kv, q, lse, q, **kw)
+
+
 # ---------------------------------------------------------------------------
 # decode attention
 # ---------------------------------------------------------------------------

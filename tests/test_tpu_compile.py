@@ -19,8 +19,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 
-# (S, H, KVH, D) of granite-3-2b and llama3-8b attention
-FLASH_SHAPES = {"granite-3-2b": (2048, 32, 8, 64), "llama3-8b": (2048, 32, 8, 128)}
+# (S, H, KVH, D) of granite-3-2b and llama3-8b attention; granite-3-2b also
+# at the benchmark's 4096 context, where 8 of the 36 live tiles of each
+# (batch row, KV head) take the masked body and 28 the unmasked one
+FLASH_SHAPES = {
+    "granite-3-2b": (2048, 32, 8, 64),
+    "granite-3-2b-s4096": (4096, 32, 8, 64),
+    "llama3-8b": (2048, 32, 8, 128),
+}
 
 
 @pytest.fixture(scope="module")

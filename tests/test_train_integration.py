@@ -61,3 +61,21 @@ def test_grad_accum_matches_full_batch():
     a = run(_args(steps=5, batch=8, grad_accum=1, seed=3))
     b = run(_args(steps=5, batch=8, grad_accum=2, seed=3))
     np.testing.assert_allclose(a["final_loss"], b["final_loss"], rtol=2e-3)
+
+
+@pytest.mark.parametrize("mode", ["interpret", None])
+def test_launcher_reports_flash_tiles_where_the_step_takes_the_kernels(
+        monkeypatch, capsys, mode):
+    """On the Pallas path (interpret mode stands in for the chip) the launcher
+    prints and returns the kernels' tile classes at ``--seq``; on the XLA
+    path it reports none."""
+    from repro.models import attention
+
+    monkeypatch.setattr(attention, "_kernel_mode", lambda: mode)
+    r = run(_args(steps=1, batch=1, seq=1024, layers=1, workers=1))
+    out = capsys.readouterr().out
+    if mode is None:
+        assert r["flash_tiles"] is None and "flash tiles" not in out
+    else:
+        assert r["flash_tiles"] == {"skipped": 1, "masked": 2, "unmasked": 1}
+        assert "[train] flash tiles skipped 1 masked 2 unmasked 1" in out
